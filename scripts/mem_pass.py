@@ -16,6 +16,10 @@ record: per-worker accumulator bytes for each stateful registry optimizer
 wire side is pinned by the audit's window-payload rule).
 
   PYTHONPATH=src python scripts/mem_pass.py [--arch X --shape Y]
+
+CPU only: it lowers for 512 forced host devices, one jax child process per
+pair, and its parent also imports jax.  Keep it off a TPU: a chip belongs
+to one process, so a parent holding it leaves the children to fail or hang.
 """
 import argparse
 import json

@@ -4,6 +4,10 @@
 single-pod per the spec).
 
   PYTHONPATH=src python scripts/sweep_dryrun.py [--skip-existing]
+
+CPU only: every pair runs in its own jax child process on forced host
+devices.  Keep it off a TPU: a chip belongs to one process at a time, so
+the children would contend for it (fail or hang).
 """
 import argparse
 import os

@@ -217,26 +217,27 @@ def window_payload_problems(hlo_text: str, expected_bytes: int, *,
             problems.append(
                 f"by_dtype buckets sum to {sum(by_dtype.values())}, "
                 f"expected_bytes says {expected_bytes}")
-        unmatched = list(ops)
+        # one bucket per op result: a combined tuple op carries several
+        unmatched = [c for o in ops for c in o["components"]]
         for tag, b in sorted(by_dtype.items()):
             hit = None
-            for o in unmatched:
-                if o["by_dtype"] == {tag: b}:
-                    hit = o          # verbatim wire dtype
+            for i, c in enumerate(unmatched):
+                if c == {tag: b}:
+                    hit = i          # verbatim wire dtype
                     break
-                if tag in ("bf16", "f16") and o["by_dtype"] == {"f32": 2 * b}:
-                    hit = o          # float-normalized to f32, same elements
+                if tag in ("bf16", "f16") and c == {"f32": 2 * b}:
+                    hit = i          # float-normalized to f32, same elements
                     break
             if hit is None:
                 problems.append(
                     f"no {op} carries the {tag} bucket of {b} bytes "
-                    f"(ops: {[(o['op'], o['by_dtype']) for o in ops]})")
+                    f"(ops: {[(o['op'], o['components']) for o in ops]})")
                 continue
-            unmatched.remove(hit)
+            unmatched.pop(hit)
         if unmatched:
-            msg = (f"stray {op} beyond the accounted dtype buckets: "
-                   f"{[(o['op'], o['by_dtype']) for o in unmatched]}")
-            stray_b = sum(o["bytes"] for o in unmatched)
+            msg = (f"stray {op} results beyond the accounted dtype buckets: "
+                   f"{unmatched}")
+            stray_b = sum(sum(c.values()) for c in unmatched)
             if opt_bytes and stray_b == opt_bytes:
                 msg += (f" — the stray bytes equal the per-worker optimizer "
                         f"state ({opt_bytes} B): optimizer state leaked "
@@ -467,7 +468,8 @@ def rule_donation(prog: CompiledProgram):
 # primitives that round-trip through the host (sync points) or move buffers
 # between devices mid-program — none belong in a jitted hot path
 _HOST_CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "infeed", "outfeed", "host_callback_call",
 })
 _TRANSFER_PRIMS = frozenset({"device_put", "copy_to_host_async"})
@@ -971,20 +973,25 @@ def capture_kernel_launches(*, impl: str = "auto", shapes=None):
     g = AK.launch_geometry(T)
     launches.append(PallasLaunch(
         kernel="auc_loss", grid=g["grid"], blocks={"t": (g["Tp"], g["bt"])},
+        alignments={"bt%128": (g["bt"], 128)},
         interpret=interpret, impl=impl))
 
     (N,) = s["prox"]
-    g = PK.launch_geometry(N)
+    g = PK.launch_geometry(N, dtypes=(jnp.bfloat16,))
     launches.append(PallasLaunch(
         kernel="prox_update", grid=g["grid"],
-        blocks={"n": (g["Np"], g["bt"])}, interpret=interpret, impl=impl))
+        blocks={"n": (g["Np"], g["bt"])},
+        alignments={"bt%bf16_rows": (g["bt"], 256)},
+        interpret=interpret, impl=impl))
 
     (N,) = s["opt"]
-    g = OK.launch_geometry(N)
+    g = OK.launch_geometry(N, dtypes=(jnp.float32, jnp.bfloat16))
     for mode in ("momentum", "precond"):
         launches.append(PallasLaunch(
             kernel=f"opt_update[{mode}]", grid=g["grid"],
-            blocks={"n": (g["Np"], g["bt"])}, interpret=interpret, impl=impl))
+            blocks={"n": (g["Np"], g["bt"])},
+            alignments={"bt%bf16_rows": (g["bt"], 256)},
+            interpret=interpret, impl=impl))
 
     B, S, nH, KV, Skv, hd = s["flash"]
     g = FK.launch_geometry(B, S, nH, KV, Skv, hd)

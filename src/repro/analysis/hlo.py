@@ -87,10 +87,13 @@ def _tuple_components(type_str: str) -> list[str]:
 
 def collective_ops(hlo_text: str) -> list[dict]:
     """One record per collective op in the optimized HLO:
-    {op, bytes, by_dtype, replica_groups}.  ``bytes`` are result-shape bytes
-    (== per-participant operand bytes for all-reduce; the gathered size for
-    all-gather).  ``replica_groups`` is the literal group string, so callers
-    can tell cross-worker reductions apart from any intra-group ones.
+    {op, bytes, by_dtype, components, replica_groups}.  ``bytes`` are
+    result-shape bytes (== per-participant operand bytes for all-reduce; the
+    gathered size for all-gather).  ``components`` holds one per-dtype byte
+    dict per result tuple element: XLA's all-reduce combiner may fuse
+    several buckets into one tuple-shaped op, and each element is still one
+    bucket.  ``replica_groups`` is the literal group string, so callers can
+    tell cross-worker reductions apart from any intra-group ones.
 
     Async pairs count ONCE: the ``-start`` op is the record (only the
     RESULT component of its (operands, results) tuple type is summed — the
@@ -112,6 +115,7 @@ def collective_ops(hlo_text: str) -> list[dict]:
             "op": m.group("op"),
             "bytes": sum(by_dtype.values()),
             "by_dtype": by_dtype,
+            "components": [_dtype_bytes(c) for c in _tuple_components(type_str)],
             "replica_groups": g.group(1) if g else "",
         })
     return ops
@@ -143,11 +147,12 @@ def verify_window_payload(hlo_text: str, expected_bytes: int, *,
         ``expected_bytes``.
       * ``by_dtype={hlo tag: bytes}`` (``coda.window_payload_by_dtype``) —
         the mixed-dtype check: each logical bucket must map to exactly one
-        op, either verbatim or *float-normalized* (backends without native
-        low-precision collectives, e.g. the CPU host backend, widen a
-        bf16/f16 all-reduce to f32 — same element count, doubled wire
-        bytes), no op may be left over, and the buckets must sum to
-        ``expected_bytes``.
+        op result (a whole op, or one element of a tuple-shaped op the
+        all-reduce combiner fused), either verbatim or *float-normalized*
+        (backends without native low-precision collectives, e.g. the CPU
+        host backend, widen a bf16/f16 all-reduce to f32 — same element
+        count, doubled wire bytes), no result may be left over, and the
+        buckets must sum to ``expected_bytes``.
 
     ``baseline_bytes``/``delta_bytes`` (always both) additionally pin the
     payload as an exact baseline + feature delta: ``expected_bytes`` must

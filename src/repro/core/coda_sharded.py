@@ -26,9 +26,8 @@ shards workers over (pod?, data); "fsdp" over (pod) only.  When K does not
 divide the worker axes (e.g. K=1, the PPD-SG degenerate case) the state is
 replicated instead — the executor stays correct with zero collectives.
 Within-worker tensor/FSDP parallelism *inside* the manual region is the
-multi-host follow-on tracked in ROADMAP.md: jax 0.4.x cannot nest
-auto-GSPMD subgroups under a manual worker axis (XLA
-``IsManualSubgroup`` check), so trailing dims stay replicated here.
+multi-host follow-on tracked in ROADMAP.md; trailing dims stay replicated
+here.
 
 Step functions are jitted once per window length with the state buffer
 donated; ``place(state)`` device_puts the state onto the mesh so the loop
@@ -58,12 +57,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
-
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding
 
 from repro.configs.base import ModelConfig
 from repro.core import bucketing, coda
@@ -89,6 +84,11 @@ class ShardedExecutor:
 
     def __init__(self, mcfg: ModelConfig, ccfg: coda.CoDAConfig, mesh, *,
                  policy: str = "replica", donate: bool = True):
+        # the executor's specs are GSPMD (Auto) shardings; jax.make_mesh
+        # types its axes Explicit by default, which would make every state
+        # leaf carry its sharding in its type and refuse plain indexing
+        mesh = Mesh(mesh.devices, mesh.axis_names,
+                    axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         self.mcfg, self.ccfg, self.mesh, self.policy = mcfg, ccfg, mesh, policy
         self.worker_axes = rules.worker_partition(mesh, policy, ccfg.n_workers)
         self._donate = (0,) if donate else ()
@@ -204,7 +204,7 @@ class ShardedExecutor:
         sm = _shard_map(body, mesh=self.mesh,
                         in_specs=in_specs,
                         out_specs=(st_specs, P(None, lead)),
-                        check_rep=False)
+                        check_vma=False)
         fn = jax.jit(sm, donate_argnums=self._donate)
         self._fns[key] = fn
         return fn
@@ -276,7 +276,7 @@ class ShardedExecutor:
         sm = _shard_map(body, mesh=self.mesh,
                         in_specs=in_specs,
                         out_specs=(st_specs, P(None, lead)),
-                        check_rep=False)
+                        check_vma=False)
         fn = jax.jit(sm, donate_argnums=self._donate)
         self._fns[key] = fn
         return fn
@@ -330,7 +330,7 @@ class ShardedExecutor:
                                               ccfg.n_workers, worker_dim=0)
         sm = _shard_map(body, mesh=self.mesh,
                         in_specs=(st_specs, ab_specs),
-                        out_specs=st_specs, check_rep=False)
+                        out_specs=st_specs, check_vma=False)
         fn = jax.jit(sm, donate_argnums=self._donate)
         self._fns[key] = fn
         return fn

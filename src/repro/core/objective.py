@@ -64,6 +64,7 @@ the paper restricted to the scalar head:
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -74,19 +75,20 @@ from repro.kernels import ops as kops
 _EPS = 1e-12
 
 
-@jax.custom_vjp
-def auc_F(h, y, a, b, alpha, p):
-    """Mean of F(w,a,b,α;z) over the batch.  h: [T] scores, y: [T] ∈ {0,1}."""
-    loss, *_ = kops.auc_loss(h, y, a, b, alpha, p)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def auc_F(h, y, a, b, alpha, p, impl="auto"):
+    """Mean of F(w,a,b,α;z) over the batch.  h: [T] scores, y: [T] ∈ {0,1}.
+    ``impl`` picks the kernel (``kernels.ops.dispatch``)."""
+    loss, *_ = kops.auc_loss(h, y, a, b, alpha, p, impl=impl)
     return loss
 
 
-def _fwd(h, y, a, b, alpha, p):
-    loss, dh, da, db, dalpha = kops.auc_loss(h, y, a, b, alpha, p)
+def _fwd(h, y, a, b, alpha, p, impl):
+    loss, dh, da, db, dalpha = kops.auc_loss(h, y, a, b, alpha, p, impl=impl)
     return loss, (dh.astype(h.dtype), da, db, dalpha)
 
 
-def _bwd(res, ct):
+def _bwd(impl, res, ct):
     dh, da, db, dalpha = res
     return (ct * dh, None, ct * da, ct * db, ct * dalpha, None)
 
@@ -246,14 +248,16 @@ class AUCObjective(Objective):
     stage_fields = ("alpha",)
     metric_name = "auc"
 
-    def __init__(self, p_pos: float = 0.5):
+    def __init__(self, p_pos: float = 0.5, impl: str = "auto"):
         self.p_pos = p_pos
+        self.impl = impl
 
     def init_duals(self, K: int):
         return {"a": _zeros(K), "b": _zeros(K), "alpha": _zeros(K)}
 
     def loss(self, h, y, duals):
-        return auc_F(h, y, duals["a"], duals["b"], duals["alpha"], self.p_pos)
+        return auc_F(h, y, duals["a"], duals["b"], duals["alpha"], self.p_pos,
+                     self.impl)
 
     def stage_duals(self, h, y, duals):
         return {"alpha": optimal_alpha(h, y)}
@@ -394,4 +398,6 @@ def for_config(ccfg) -> Objective:
     name = getattr(ccfg, "objective", "auc")
     if name == "pauc_dro":
         return PAUCDROObjective(p_pos=ccfg.p_pos, beta=ccfg.pauc_beta)
+    if name == "auc":
+        return AUCObjective(p_pos=ccfg.p_pos, impl=ccfg.impl)
     return REGISTRY[name](p_pos=ccfg.p_pos)

@@ -97,9 +97,7 @@ def grouped_matmul(x, w, group_sizes, *, impl: str = "auto"):
 
     The compute core of the sorted dropless MoE dispatch (models/moe.py):
     "auto" runs the tile-aligned Pallas kernel on TPU and the blocked-scan
-    jnp reference everywhere else — never interpret-mode Pallas (and never
-    ``lax.ragged_dot``, whose only jax-0.4.x lowering densifies to
-    [E, N, K]).
+    jnp reference everywhere else — never interpret-mode Pallas.
     """
     use_pallas, interpret = dispatch(impl)
     if use_pallas:

@@ -22,9 +22,9 @@ Both end with the proximal projection v' = (γ(v − η d) + η v₀)/(η + γ).
 
 Scalars (η, γ, coef) ride SMEM so a schedule's changing η never
 re-specializes the kernel; the uint32 stochastic-rounding seed rides its own
-SMEM lane (it must not round-trip through f32).  Geometry mirrors
-``prox_update``: flat 1-D layout, ``block``-wide tiles, grid exposed via
-``launch_geometry`` for the audit's R5 static-geometry rule.
+SMEM lane (it must not round-trip through f32).  Geometry is
+``prox_update``'s ``launch_geometry``: flat 1-D layout, ``block``-wide
+tiles aligned for the narrowest operand dtype, audited by rule R5.
 """
 from __future__ import annotations
 
@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
+from repro.kernels.prox_update import launch_geometry
 
 
 def _kernel(mode, scal_ref, seed_ref, v_ref, g_ref, v0_ref, buf_ref,
@@ -60,14 +61,6 @@ def _kernel(mode, scal_ref, seed_ref, v_ref, g_ref, v0_ref, buf_ref,
     buf_out_ref[...] = new_buf
 
 
-def launch_geometry(N: int, *, block: int = 4096) -> dict:
-    """Static launch geometry (audited by rule R5): tile width ``bt``,
-    padded length ``Np`` (multiple of ``bt``), 1-D ``grid``."""
-    bt = min(block, max(8, N))
-    n = -(-N // bt)
-    return {"bt": bt, "Np": n * bt, "grid": (n,)}
-
-
 @functools.partial(jax.jit, static_argnames=("mode", "block", "interpret"))
 def opt_update(v, g, v0, buf, eta, gamma, coef, seed, *, mode: str,
                block: int = 4096, interpret: bool = False):
@@ -75,7 +68,8 @@ def opt_update(v, g, v0, buf, eta, gamma, coef, seed, *, mode: str,
     if mode not in ("momentum", "precond"):
         raise ValueError(f"unknown opt_update mode {mode!r}")
     N = v.shape[0]
-    geo = launch_geometry(N, block=block)
+    geo = launch_geometry(N, block=block,
+                          dtypes=(v.dtype, g.dtype, v0.dtype, buf.dtype))
     bt, Np = geo["bt"], geo["Np"]
     pad = lambda x: jnp.pad(x, (0, Np - N))
     scal = jnp.stack([jnp.asarray(eta, jnp.float32),

@@ -27,10 +27,18 @@ def _kernel(scal_ref, v_ref, g_ref, v0_ref, out_ref):
     out_ref[...] = out.astype(out_ref.dtype)
 
 
-def launch_geometry(N: int, *, block: int = 4096) -> dict:
-    """Static launch geometry of one prox_update call, shared with the
-    auditor's R5 rule (analysis/audit.py)."""
-    bt = min(block, max(8, N))
+def launch_geometry(N: int, *, block: int = 4096, dtypes=(jnp.float32,)) -> dict:
+    """Static launch geometry of one flat elementwise update over ``N``
+    elements whose operands have ``dtypes``; shared with ``opt_update`` and
+    with the auditor's R5 rule (analysis/audit.py).
+
+    Mosaic lays a 1-D block out in 128-lane rows and packs ``4 // itemsize``
+    narrow elements per 32-bit word, so a block must span a whole number of
+    packed rows: 128 elements for f32, 256 for bf16.  ``bt`` is rounded up
+    to that alignment (the tail is padded) and ``block`` is a multiple of it.
+    """
+    align = 128 * max(4 // jnp.dtype(d).itemsize for d in dtypes)
+    bt = min(-(-block // align) * align, -(-max(N, 1) // align) * align)
     n = -(-N // bt)
     return {"bt": bt, "Np": n * bt, "grid": (n,)}
 
@@ -39,7 +47,7 @@ def launch_geometry(N: int, *, block: int = 4096) -> dict:
 def prox_update(v, g, v0, eta, gamma, *, block: int = 4096, interpret: bool = False):
     """Flat arrays v, g, v0: [N].  eta may be traced; gamma static-ish scalar."""
     N = v.shape[0]
-    geo = launch_geometry(N, block=block)
+    geo = launch_geometry(N, block=block, dtypes=(v.dtype, g.dtype, v0.dtype))
     bt, Np = geo["bt"], geo["Np"]
     pad = lambda x: jnp.pad(x, (0, Np - N))
     scal = jnp.stack([jnp.asarray(eta, jnp.float32), jnp.asarray(gamma, jnp.float32)])

@@ -60,14 +60,10 @@ def force_host_device_count(n: int) -> None:
 
 
 def abstract_mesh(shape, axis_names):
-    """Version-portable AbstractMesh: jax 0.4.x takes a tuple of
-    (name, size) pairs, 0.5+ takes (axis_sizes, axis_names)."""
+    """A device-free ``AbstractMesh`` with these axis sizes and names."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(zip(axis_names, shape)))   # 0.4.x
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axis_names))  # 0.5+
+    return AbstractMesh(tuple(shape), tuple(axis_names))
 
 
 def coda_worker_axes(policy: str, multi_pod: bool):

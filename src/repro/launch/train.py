@@ -71,6 +71,8 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import time
 
 import jax
@@ -85,6 +87,8 @@ from repro.data import DataConfig, ShardedDataset
 from repro.launch import mesh as mesh_mod
 from repro.metrics import report as metric_report
 from repro.metrics import streaming
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
 
 
 def data_config_for(mcfg, p_pos: float) -> DataConfig:
@@ -117,7 +121,25 @@ def make_batch_adapters(mcfg, ds: ShardedDataset, key):
     return adapt
 
 
-def main():
+def use_compile_cache(root: pathlib.Path = REPO_ROOT) -> None:
+    """Keep JAX's persistent compilation cache at ``<root>/.jax_cache``.
+
+    The path is fixed because it is part of the cache key: a directory that
+    moves never hits.  When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads
+    it itself and no path is set here.  Entry points call this; importing
+    ``repro`` never does."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+
+
+def device_summary() -> dict:
+    """The platform, kind and count of the devices JAX runs on."""
+    d = jax.devices()
+    return {"platform": d[0].platform, "kind": d[0].device_kind,
+            "count": len(d)}
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mlp")
     ap.add_argument("--smoke", action="store_true",
@@ -222,10 +244,23 @@ def main():
     ap.add_argument("--multi-pod", action="store_true",
                     help="use the 3-axis (pod, data, model) mesh layout")
     metric_report.add_metric_args(ap)
-    args = ap.parse_args()
+    return ap
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
     if args.force_host_devices:
         mesh_mod.force_host_device_count(args.force_host_devices)
+    use_compile_cache()
+    run(args)
+
+
+def run(args) -> dict:
+    """Train as the parsed ``args`` say and print the run summary.  Returns
+    the ``FitResult`` (``fit``), the test AUC (``auc``) and scores
+    (``scores``), the mesh (None for the vmap executor), the model and CoDA
+    configs and the stage list."""
+    print("device:", device_summary())
 
     if args.arch == "mlp":
         mcfg = mlp_config()
@@ -294,13 +329,19 @@ def main():
 
     test = adapt(ds.full(2048))
     obj = objective.for_config(ccfg)
+    from repro.models import model as M
+    score = jax.jit(lambda p, x: M.score(mcfg, p, x)[0])
 
-    def test_scores(state):
-        from repro.models import model as M
+    def test_scores(state, chunk: int = 256):
+        # jitted and chunked: an eager full-width forward over the whole
+        # split would hold every layer's activations for 2048 inputs at once
         params0 = jax.tree_util.tree_map(lambda x: x[0], state["params"])
         inputs = {k: v for k, v in test.items() if k != "labels"}
-        h, _ = M.score(mcfg, params0, inputs)
-        return h
+        n = len(test["labels"])
+        return jnp.concatenate([
+            score(params0, jax.tree_util.tree_map(lambda v: v[i:i + chunk],
+                                                  inputs))
+            for i in range(0, n, chunk)])
 
     # the eval hook reports through the shared metric plumbing: sketch mode
     # lifts the in-training streaming accumulator (state["sk_acc"], merged on
@@ -373,6 +414,9 @@ def main():
         path = checkpoint.save(args.ckpt_dir, res.iterations, res.state,
                                {"auc": auc, "arch": mcfg.name})
         print("checkpoint:", path)
+    return {"fit": res, "auc": auc, "scores": h_test, "mesh": mesh,
+            "mcfg": mcfg, "ccfg": ccfg,
+            "stages": schedules.stages(sched, args.stages)}
 
 
 if __name__ == "__main__":

@@ -131,7 +131,7 @@ def _jaxpr_of(fn, *args):
 
 
 def test_r3_rejects_f64_literal_in_hot_path():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = _jaxpr_of(lambda v: v * jnp.float64(2.5),
                           jnp.arange(4, dtype=jnp.float64))
     problems = A.jaxpr_problems(jaxpr)
@@ -281,10 +281,7 @@ def test_real_stack_passes_and_smuggled_pmean_fails():
     broken one on the same mesh."""
     _run("""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = jax.make_mesh((8, 1), ("data", "model"))
     for algorithm in ("coda", "codasca"):

@@ -116,3 +116,39 @@ def test_explicit_pallas_interprets_off_tpu():
     np.testing.assert_allclose(
         np.asarray(got["w"]),
         np.asarray(ref.prox_update_ref(v, v, v, 0.1, 0.5)), atol=1e-5)
+
+
+def test_coda_impl_reaches_auc_loss_and_ref_matches_pallas_window(monkeypatch):
+    """``CoDAConfig.impl`` must reach the fused AUC loss inside a training
+    window: impl="pallas" traces the (interpret-mode) AUC kernel, impl="ref"
+    never does, and the two windows agree on params and duals."""
+    from repro.configs.base import mlp_config
+    from repro.core import coda
+
+    mcfg = mlp_config(n_features=16, d=32)
+    K, I, B = 2, 2, 16
+    ky, kx = jax.random.split(jax.random.PRNGKey(4))
+    y = (jax.random.uniform(ky, (I, K, B)) < 0.7).astype(jnp.float32)
+    x = jax.random.normal(kx, (I, K, B, 16)) + 0.3 * (y[..., None] * 2 - 1)
+    wb = {"features": x, "labels": y}
+
+    calls = []
+    real = ops._auc_kernel
+
+    def counting(*a, **k):
+        calls.append(k.get("interpret"))
+        return real(*a, **k)
+
+    monkeypatch.setattr(ops, "_auc_kernel", counting)
+    out = {}
+    for impl in ("ref", "pallas"):
+        del calls[:]
+        ccfg = coda.CoDAConfig(n_workers=K, p_pos=0.7, impl=impl)
+        st0 = coda.init_state(jax.random.PRNGKey(0), mcfg, ccfg)
+        out[impl], _ = coda.window_step(mcfg, ccfg, st0, wb, 0.1)
+        assert (len(calls) > 0) == (impl == "pallas"), (impl, calls)
+    for part in ("params", "duals"):
+        for a, b in zip(jax.tree_util.tree_leaves(out["ref"][part]),
+                        jax.tree_util.tree_leaves(out["pallas"][part])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6)
