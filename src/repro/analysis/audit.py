@@ -955,7 +955,8 @@ def capture_kernel_launches(*, impl: str = "auto", shapes=None):
     from repro.kernels import prox_update as PK
 
     s = {"moe": (64, 32, 4, 64), "auc": (300,), "prox": (1000,),
-         "opt": (1000,), "flash": (1, 256, 4, 2, 256, 64)}
+         "prox_tiled": (100352, 2048), "opt": (1000,),
+         "flash": (1, 256, 4, 2, 256, 64)}
     s.update(shapes or {})
     _, interpret = kops.dispatch(impl)
     launches = []
@@ -983,6 +984,22 @@ def capture_kernel_launches(*, impl: str = "auto", shapes=None):
         blocks={"n": (g["Np"], g["bt"])},
         alignments={"bt%bf16_rows": (g["bt"], 256)},
         interpret=interpret, impl=impl))
+
+    # rank >= 2 leaves: (rows, lanes) blocks on the leaf's own layout; each
+    # minor block is aligned to the tile or spans its whole axis
+    g = PK.tiled_geometry(s["prox_tiled"])
+    rows, lanes = g["view"][-2:]
+    nr, nl = g["grid"][-2:]
+    aligned = {}
+    if g["rows"] != rows:
+        aligned["rows%sublanes"] = (g["rows"], g["sublanes"])
+    if g["lanes"] != lanes:
+        aligned["lanes%128"] = (g["lanes"], 128)
+    launches.append(PallasLaunch(
+        kernel="prox_update[tiled]", grid=g["grid"],
+        blocks={"rows": (nr * g["rows"], g["rows"]),
+                "lanes": (nl * g["lanes"], g["lanes"])},
+        alignments=aligned, interpret=interpret, impl=impl))
 
     (N,) = s["opt"]
     g = OK.launch_geometry(N, dtypes=(jnp.float32, jnp.bfloat16))

@@ -127,14 +127,13 @@ def opt_update(v, g, v0, buf, eta, gamma, coef, seed, *, mode: str,
 
 
 def prox_update_tree(v_tree, g_tree, v0_tree, eta, gamma, *, impl: str = "auto"):
-    """Apply the fused proximal update leaf-wise over parameter pytrees."""
+    """Apply the fused proximal update leaf-wise over parameter pytrees,
+    each leaf in its own shape (the kernel picks its launch from the rank)."""
     use_pallas, interpret = dispatch(impl)
 
     def upd(v, g, v0):
         if use_pallas:
-            flat = _prox_kernel(v.reshape(-1), g.reshape(-1), v0.reshape(-1),
-                                eta, gamma, interpret=interpret)
-            return flat.reshape(v.shape)
+            return _prox_kernel(v, g, v0, eta, gamma, interpret=interpret)
         return ref.prox_update_ref(v, g, v0, eta, gamma)
 
     return jax.tree_util.tree_map(upd, v_tree, g_tree, v0_tree)

@@ -227,10 +227,52 @@ def test_r5_real_kernel_geometry_passes_including_ragged_tails():
     # ragged problem sizes that historically tripped tile math
     ragged = A.capture_kernel_launches(
         impl="ref", shapes={"moe": (7, 5, 3, 9), "auc": (12,),
-                            "prox": (5,), "flash": (1, 8, 4, 2, 8, 64)})
+                            "prox": (5,), "prox_tiled": (3, 300, 1000),
+                            "flash": (1, 8, 4, 2, 8, 64)})
     for launch in ragged:
         assert A.launch_problems(launch) == [], launch
     assert A.dispatch_problems() == []
+
+
+def _leaves(*shapes):
+    return [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+
+
+# stand-ins for one worker's parameters in the benchmark's two configurations,
+# one leaf of each kind: (matrices and stacks, rank-1 vectors)
+_STAND_INS = {
+    # embedding, stacked attention, SwiGLU and LayerNorm/bias leaves (6
+    # layers), score head; the final LayerNorm and the head's bias are rank 1
+    "stablelm2-1.6b-share": (
+        _leaves((100352, 2048), (6, 2048, 2048), (6, 2048, 5632),
+                (6, 5632, 2048), (6, 2048), (2048, 1)),
+        _leaves((2048,), (2048,), (1,))),
+    # stem, 3x3 and 1x1 convs, score head; GroupNorm scales and biases, the
+    # head's bias
+    "resnet50-cifar32": (
+        _leaves((3, 3, 3, 64), (3, 3, 256, 256), (1, 1, 512, 2048),
+                (2048, 1)),
+        _leaves((64,), (256,), (2048,), (1,))),
+}
+
+
+@pytest.mark.parametrize("config", list(_STAND_INS))
+def test_tiled_share_of_the_benchmark_parameter_trees(config):
+    """The share of parameters the update runs on their own tiled layout,
+    from shapes alone: one worker's tree leaves out exactly its rank-1
+    vectors, and with the worker axis leading, as the update sees every
+    leaf, the whole tree is tiled."""
+    from repro.kernels.prox_update import tiled_share
+
+    tiled, vectors = _STAND_INS[config]
+    n = lambda leaves: sum(x.size for x in leaves)
+    tree = {"tiled": tiled, "vectors": vectors}
+    assert tiled_share(tree) == n(tiled) / (n(tiled) + n(vectors))
+    assert tiled_share({"vectors": vectors}) == 0.0
+    for K in (1, 4):
+        workers = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((K,) + x.shape, x.dtype), tree)
+        assert tiled_share(workers) == 1.0
 
 
 # --------------------------------------------------------------------------

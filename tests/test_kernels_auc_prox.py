@@ -89,6 +89,47 @@ def test_prox_kernel_vs_ref(N, block, dtype):
                                if dtype == jnp.bfloat16 else 1e-6)
 
 
+# small stand-ins for every leaf kind of the benchmark's configurations, as
+# the update sees them (the worker axis leading); most are large enough to
+# take several ~1 MiB blocks, with a partial block at the edge
+_TILED_CASES = {
+    "embedding-rank2": ((512, 2048), jnp.float32, False),
+    "stacked-rank3": ((2, 256, 1536), jnp.float32, False),
+    "stacked-norms-6-rows": ((1, 6, 256), jnp.float32, False),
+    "two-squeezed-axes": ((2, 2, 300, 1000), jnp.float32, False),
+    "conv-64-lanes": ((3, 3, 512, 64), jnp.float32, False),
+    "conv-256-lanes": ((3, 3, 128, 256), jnp.float32, False),
+    "score-head-2048x1": ((2048, 1), jnp.float32, False),
+    "rank1": ((100,), jnp.float32, False),
+    "vmapped-K4-conv": ((4, 3, 3, 128, 256), jnp.float32, True),
+    "bf16": ((600, 2048), jnp.bfloat16, False),
+    "ragged-rows": ((300, 2048), jnp.float32, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_TILED_CASES))
+def test_prox_kernel_own_layout_vs_ref_and_flat(case):
+    """A leaf updated in its own shape equals the reference, and equals the
+    flat launch over the same elements bit for bit (same body, same f32
+    expression)."""
+    shape, dtype, vmapped = _TILED_CASES[case]
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    v, g, v0 = (jax.random.normal(k, shape, dtype) for k in (k1, k2, k3))
+    upd = lambda v, g, v0: prox_update(v, g, v0, 0.05, 0.5, interpret=True)
+    if vmapped:
+        upd = jax.vmap(upd)
+    got = upd(v, g, v0)
+    flat = prox_update(v.reshape(-1), g.reshape(-1), v0.reshape(-1), 0.05,
+                       0.5, block=1 << 16, interpret=True).reshape(shape)
+    exp = ref.prox_update_ref(v, g, v0, 0.05, 0.5)
+    assert got.shape == shape and got.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(flat, np.float32))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(exp, np.float32), atol=2e-2
+                               if dtype == jnp.bfloat16 else 1e-6)
+
+
 @settings(max_examples=30, deadline=None)
 @given(eta=st.floats(1e-4, 1.0), gamma=st.floats(1e-3, 10.0),
        seed=st.integers(0, 2 ** 16))

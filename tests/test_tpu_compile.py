@@ -14,6 +14,7 @@ module is imported: only one process may load the TPU library at a time,
 and every pytest-xdist worker imports this file.
 """
 import importlib.util
+import math
 import os
 import pathlib
 import re
@@ -24,16 +25,19 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.auc_loss import auc_loss
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_dispatch import grouped_matmul
 from repro.kernels.opt_update import opt_update
-from repro.kernels.prox_update import prox_update
+from repro.kernels.prox_update import prox_update, tiled_geometry
 
 CHIP_BENCH = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 
 # one 3x3x256x256 conv of ResNet-50's stage 3 (the largest kernel leaf)
 RESNET50_CONV_LEAF = 2_359_296
+# the scoped VMEM a v5e kernel may use unless it asks for more
+V5E_SCOPED_VMEM = 16 << 20
 
 
 @pytest.fixture(scope="module")
@@ -108,6 +112,42 @@ def test_prox_update_compiles_for_v5e(one_chip, N, dtype):
     txt = _compiled_text(lambda v, g, v0, eta: prox_update(v, g, v0, eta, 0.5),
                          s(), s(), s(), _spec(one_chip, ()))
     _assert_named(txt, "prox_update")
+
+
+def _ops_on_leaf_size(txt: str, size: int, opcodes) -> list[str]:
+    """Instructions of ``opcodes`` whose result holds ``size`` elements."""
+    return [f"{op} {shape}" for shape, dims, op in re.findall(
+        r"^\s*(?:ROOT )?%\S+ = (\w+\[([\d,]*)\])\S* ([\w-]+)\(", txt, re.M)
+        if op in opcodes
+        and math.prod(int(d) for d in dims.split(",") if d) == size]
+
+
+@pytest.mark.parametrize("shape,vmapped", [
+    ((100352, 2048), False),       # StableLM-2's embedding
+    ((6, 2048, 5632), False),      # six stacked SwiGLU projections
+    ((4, 3, 3, 256, 256), True),   # a ResNet-50 conv, vmapped over K = 4
+])
+def test_prox_update_tree_keeps_the_leafs_layout(one_chip, monkeypatch,
+                                                 shape, vmapped):
+    """Through ``ops.prox_update_tree``'s kernel path a leaf is updated in
+    its own tiled layout: one Mosaic call, and no copy, reshape or
+    transpose of the leaf's size around it (the collapse of leading axes
+    into rows is a bitcast), nor of its parameters: the kernel writes in
+    place, so with v donated, as the window's loop carries it, no copy of
+    v outlives the call.  Its double-buffered blocks fit the scoped VMEM."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    upd = lambda v, g, v0, eta: ops.prox_update_tree(v, g, v0, eta, 0.5)
+    if vmapped:
+        upd = jax.vmap(upd, in_axes=(0, 0, 0, None))
+    s = lambda: _spec(one_chip, shape)
+    txt = jax.jit(upd, donate_argnums=0).lower(
+        s(), s(), s(), _spec(one_chip, ())).compile().as_text()
+    _assert_named(txt, "prox_update")
+    assert len(_kernel_calls(txt)) == 1
+    assert _ops_on_leaf_size(txt, math.prod(shape), ("copy", "reshape", "transpose",
+                                         "copy-start", "pad", "slice")) == []
+    leaf = shape[1:] if vmapped else shape
+    assert tiled_geometry(leaf)["vmem_bytes"] <= V5E_SCOPED_VMEM
 
 
 def test_prox_update_roofline_finds_the_update_kernel(one_chip):
