@@ -7,6 +7,7 @@ from repro.configs import (
     dbrx_132b,
     hymba_1_5b,
     internvl2_2b,
+    moonlight_16b_a3b,
     phi3_medium_14b,
     qwen2_5_14b,
     resnet50,
@@ -27,6 +28,7 @@ _MODULES = {
     "hymba-1.5b": hymba_1_5b,
     "phi3-medium-14b": phi3_medium_14b,
     "xlstm-350m": xlstm_350m,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
     "resnet50": resnet50,
 }
 

@@ -19,29 +19,58 @@ import jax.numpy as jnp
 class MoEConfig:
     """Mixture-of-experts block configuration.
 
-    ``dispatch`` selects the EVAL/DECODE dispatch implementation (training
-    always uses capacity-factor dispatch — dropping over-capacity tokens is
-    the load-shedding regularizer):
-      * "sorted"   — dropless sort-based dispatch: [T·k, d] buffer + ragged
-                     grouped GEMM over expert segments (models/moe.py).
-      * "capacity" — the padded scatter dispatch at the static dropless
-                     bound C = T: an [E, T, d] buffer, ~E/top_k-fold
-                     oversized in expectation (kept for A/B and as the
-                     oracle the sorted path is tested against).
+    ``dispatch`` selects the dispatch implementation, in training and in
+    eval/decode alike:
+      * "sorted"   — dropless sort-based dispatch: the rows routed to the
+                     held experts, sorted by expert, + ragged grouped GEMM
+                     over expert segments (models/moe.py).
+      * "capacity" — the padded scatter dispatch through an [E, C, d]
+                     buffer: the capacity-factor bound in training (over-
+                     capacity tokens are dropped), the static dropless bound
+                     C = T at eval (kept for A/B and as the oracle the
+                     sorted path is tested against).
+
+    ``scoring``: "softmax" (top-k of a softmax, Switch auxiliary loss) or
+    "sigmoid" (DeepSeek-V3's ``noaux_tc`` with one group: top-k of sigmoid
+    scores plus a selection-bias leaf, gate weights the unbiased scores of
+    the chosen experts; no auxiliary loss).  Gate weights are renormalised
+    over the chosen k and scaled by ``routed_scale``.
+
+    The held share: this layer holds experts ``[held_offset, held_offset +
+    n_held)`` of ``n_experts`` (``n_held`` 0: all of them), routes every
+    token over all ``n_experts`` and computes only its own experts' part
+    (expert parallelism without the exchange).
     """
 
     n_experts: int
     top_k: int
     capacity_factor: float = 1.25
-    # Arctic keeps a dense (always-on) residual MLP next to the experts.
+    # An always-on MLP of width ``dense_d_ff`` next to the experts: Arctic's
+    # dense residual, or DeepSeek's shared experts (their count x expert width).
     dense_residual: bool = False
     dense_d_ff: int = 0
     dispatch: str = "sorted"
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    held_offset: int = 0
+    n_held: int = 0
 
     def __post_init__(self):
         if self.dispatch not in ("sorted", "capacity"):
             raise ValueError(
                 f"unknown moe dispatch {self.dispatch!r} (want sorted | capacity)")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown moe scoring {self.scoring!r} (want softmax | sigmoid)")
+        if self.n_held == 0:
+            object.__setattr__(self, "n_held", self.n_experts)
+        if not 0 <= self.held_offset <= self.n_experts - self.n_held:
+            raise ValueError(f"held experts [{self.held_offset}, "
+                             f"{self.held_offset + self.n_held}) outside "
+                             f"the {self.n_experts} routed experts")
+        if self.n_held < self.n_experts and self.dispatch != "sorted":
+            raise ValueError("a held share of the experts needs the sorted "
+                             "dispatch")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +96,14 @@ class ModelConfig:
     rope_fraction: float = 1.0  # fraction of head_dim that is rotated
     rope_base: float = 10000.0
     qkv_bias: bool = False
+    # Multi-head latent attention (DeepSeek-V2/V3) where ``kv_lora_rank`` > 0:
+    # keys and values come up from one RMS-normed latent of that width, each
+    # head's query and key are ``head_dim`` wide, their last
+    # ``qk_rope_head_dim`` rotated (the key's rotated part is one head shared
+    # by all), and values are ``v_head_dim`` wide.  No query compression.
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
     act: str = "swiglu"  # "swiglu" | "gelu"
     # Sliding-window attention.  ``window`` is the size used when a layer is
@@ -79,6 +116,11 @@ class ModelConfig:
 
     # --- mixture of experts -------------------------------------------------
     moe: MoEConfig | None = None
+    # Leading dense layers ahead of the MoE stack (DeepSeek's
+    # ``first_k_dense_replace``), with an MLP of their own width; they count
+    # in ``n_layers``.
+    dense_layers: int = 0
+    dense_layers_d_ff: int = 0
 
     # --- state-space / hybrid ----------------------------------------------
     ssm_state: int = 0  # N for mamba-style SSM (hymba)

@@ -3,8 +3,8 @@
   * flash_attention — dominant FLOP consumer of every backbone
   * auc_loss        — the paper's fused min-max objective + closed-form grads
   * prox_update     — CoDA's fused proximal local update (3 model copies/step)
-  * moe_dispatch    — grouped expert GEMM for sorted dropless MoE dispatch
-                      (the eval/decode serving hot path)
+  * moe_dispatch    — grouped expert GEMM for sorted dropless MoE dispatch,
+                      forward and backward (training, eval and decode)
 
 Each has a pure-jnp oracle in ``ref.py`` and a jit'd dispatcher in ``ops.py``.
 """

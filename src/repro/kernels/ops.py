@@ -57,17 +57,21 @@ def dispatch(impl: str) -> tuple:
 
 
 def attention(q, k, v, *, causal: bool = True, window=None, impl: str = "auto"):
-    """GQA attention.  q: [B,S,H,hd], k/v: [B,Skv,KV,hd] -> [B,S,H,hd].
+    """GQA attention.  q: [B,S,H,hd], k: [B,Skv,KV,hd], v: [B,Skv,KV,hv]
+    -> [B,S,H,hv].
 
     ``window``: None / -1 = full; a Python int enables the Pallas kernel's
     block skipping; a traced scalar falls back to masked jnp (used inside
-    scanned heterogeneous stacks, e.g. Hymba).
+    scanned heterogeneous stacks, e.g. Hymba).  The flash kernel holds one
+    head width, so a narrower value head (latent attention) takes the jnp
+    path.
     """
     static_window = window is None or isinstance(window, int)
     if static_window and isinstance(window, int) and window < 0:
         window = None
     use_pallas, interpret = dispatch(impl)
-    if use_pallas and (static_window or impl == "pallas"):
+    if (use_pallas and (static_window or impl == "pallas")
+            and v.shape[-1] == q.shape[-1]):
         return _flash(q, k, v, causal=causal, window=window,
                       interpret=interpret)
     if k.shape[1] <= _FULL_ATTN_MAX_KV:
@@ -93,7 +97,8 @@ def auc_loss(h, y, a, b, alpha, p, *, impl: str = "auto"):
 
 def grouped_matmul(x, w, group_sizes, *, impl: str = "auto"):
     """Ragged grouped GEMM: out[i] = x[i] @ w[g(i)] for rows sorted by
-    group.  x: [N, K]; w: [E, K, F]; group_sizes: [E] (sum == N).
+    group.  x: [N, K]; w: [E, K, F]; group_sizes: [E] (sum <= N: rows
+    past the sum belong to no group and give 0).
 
     The compute core of the sorted dropless MoE dispatch (models/moe.py):
     "auto" runs the tile-aligned Pallas kernel on TPU and the blocked-scan

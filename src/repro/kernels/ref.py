@@ -24,8 +24,11 @@ def _mask(q_pos, kv_pos, causal: bool, window):
 
 
 def attention_full(q, k, v, *, causal=True, window=None):
-    """q: [B, S, H, hd]; k/v: [B, Skv, KV, hd] -> [B, S, H, hd]."""
+    """q/k: [B, S(kv), H(KV), hd]; v: [B, Skv, KV, hv] -> [B, S, H, hv].
+    Scores are scaled by hd ** -0.5; the value head may be narrower than
+    the query/key head (latent attention: 128 against 192)."""
     B, S, H, hd = q.shape
+    hv = v.shape[-1]
     KV = k.shape[2]
     G = H // KV
     qg = q.reshape(B, S, KV, G, hd).astype(jnp.float32) * hd ** -0.5
@@ -34,7 +37,7 @@ def attention_full(q, k, v, *, causal=True, window=None):
     s = jnp.where(valid[None, :, None, None, :], s, -1e30)
     w = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bskgc,bckh->bskgh", w, v.astype(jnp.float32))
-    return o.reshape(B, S, H, hd).astype(q.dtype)
+    return o.reshape(B, S, H, hv).astype(q.dtype)
 
 
 def attention_chunked(q, k, v, *, causal=True, window=None, chunk=512):
@@ -46,12 +49,13 @@ def attention_chunked(q, k, v, *, causal=True, window=None, chunk=512):
     from repro import flags
     B, S, H, hd = q.shape
     Skv, KV = k.shape[1], k.shape[2]
+    hv = v.shape[-1]
     G = H // KV
     C = min(flags.attn_chunk(Skv, chunk), Skv)
     assert Skv % C == 0, (Skv, C)
     qg = q.reshape(B, S, KV, G, hd).astype(jnp.float32) * hd ** -0.5
     kc = jnp.moveaxis(k.reshape(B, Skv // C, C, KV, hd), 1, 0)
-    vc = jnp.moveaxis(v.reshape(B, Skv // C, C, KV, hd), 1, 0)
+    vc = jnp.moveaxis(v.reshape(B, Skv // C, C, KV, hv), 1, 0)
     q_pos = jnp.arange(S)
 
     def step(carry, inp):
@@ -71,12 +75,12 @@ def attention_chunked(q, k, v, *, causal=True, window=None, chunk=512):
 
     m0 = jnp.full((B, S, KV, G), -1e30, jnp.float32)
     l0 = jnp.zeros((B, S, KV, G), jnp.float32)
-    a0 = jnp.zeros((B, S, KV, G, hd), jnp.float32)
+    a0 = jnp.zeros((B, S, KV, G, hv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, a0), (jnp.arange(Skv // C), kc, vc),
         unroll=flags.scan_unroll())
     o = acc / jnp.maximum(l, 1e-30)[..., None]
-    return o.reshape(B, S, H, hd).astype(q.dtype)
+    return o.reshape(B, S, H, hv).astype(q.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -116,14 +120,16 @@ def grouped_layout(group_sizes, n_rows: int, block_rows: int):
     """Row mapping for a tile-aligned grouped layout.
 
     Pads each group's row segment up to a multiple of ``block_rows`` so
-    every row tile belongs to exactly ONE group.  Returns
-    ``(dst [N], tile_gid [n_tiles], n_padded)``: sorted row i lands at
-    ``dst[i]`` in the padded buffer and tile t is owned by group
-    ``tile_gid[t]``.  ``n_padded`` is the static bound
-    ``round_up(N, bm) + min(E, N)·bm`` — at most one tile of slack per
-    NON-EMPTY group (at most min(E, N) of those), negligible next to the
-    capacity path's E/top_k-fold padding.  Shared by the jnp reference
-    below and the Pallas kernel (kernels/moe_dispatch.py).
+    every row tile belongs to exactly ONE group.  Rows past
+    ``sum(group_sizes)`` are dead (routed to no group here): they land in
+    the tiles after the last live one.  Returns ``(dst [N], tile_gid
+    [n_tiles], n_live_tiles, n_padded)``: sorted row i lands at ``dst[i]``
+    in the padded buffer, tile t is owned by group ``tile_gid[t]``, and
+    tiles ``[0, n_live_tiles)`` hold every live row.  ``n_padded`` is the
+    static bound ``round_up(N, bm) + min(E, N)·bm`` — at most one tile of
+    slack per NON-EMPTY group (at most min(E, N) of those), negligible next
+    to the capacity path's E/top_k-fold padding.  Shared by the jnp
+    reference below and the Pallas kernels (kernels/moe_dispatch.py).
     """
     E = group_sizes.shape[0]
     gs = group_sizes.astype(jnp.int32)
@@ -133,15 +139,18 @@ def grouped_layout(group_sizes, n_rows: int, block_rows: int):
     pinc = jnp.cumsum(pc)
     pexc = pinc - pc
     rows = jnp.arange(n_rows, dtype=jnp.int32)
-    g_row = jnp.clip(jnp.searchsorted(inc, rows, side="right"), 0, E - 1)
-    dst = pexc[g_row] + (rows - exc[g_row])
+    g_row = jnp.searchsorted(inc, rows, side="right")
+    dead = g_row >= E
+    g_row = jnp.minimum(g_row, E - 1)
+    dst = jnp.where(dead, pinc[-1] + rows - inc[-1],
+                    pexc[g_row] + (rows - exc[g_row]))
     n_padded = (_round_up(max(n_rows, 1), block_rows)
                 + min(E, max(n_rows, 1)) * block_rows)
     tile_starts = jnp.arange(n_padded // block_rows,
                              dtype=jnp.int32) * block_rows
     tile_gid = jnp.clip(jnp.searchsorted(pinc, tile_starts, side="right"),
                         0, E - 1).astype(jnp.int32)
-    return dst, tile_gid, n_padded
+    return dst, tile_gid, pinc[-1] // block_rows, n_padded
 
 
 def grouped_matmul_ref(x, w, group_sizes, *, block_rows: int = 128):
@@ -149,7 +158,8 @@ def grouped_matmul_ref(x, w, group_sizes, *, block_rows: int = 128):
 
     x: [N, K] with the first ``group_sizes[0]`` rows belonging to group 0,
     the next ``group_sizes[1]`` to group 1, ...; w: [E, K, F];
-    group_sizes: [E] int with ``sum == N``.  Returns [N, F].
+    group_sizes: [E] int with ``sum <= N``: rows past the sum belong to no
+    group and give 0.  Returns [N, F].
 
     NOT ``lax.ragged_dot``: on jax 0.4.x that primitive's only lowering is
     ragged_to_dense — it materializes a masked [E, N, K] operand, i.e.
@@ -162,7 +172,7 @@ def grouped_matmul_ref(x, w, group_sizes, *, block_rows: int = 128):
     N, K = x.shape
     E, _, F = w.shape
     bm = min(block_rows, _round_up(max(N, 1), 8))
-    dst, tile_gid, Np = grouped_layout(group_sizes, N, bm)
+    dst, tile_gid, _, Np = grouped_layout(group_sizes, N, bm)
     xb = jnp.zeros((Np, K), x.dtype).at[dst].set(x).reshape(-1, bm, K)
 
     def body(_, inp):
@@ -170,7 +180,8 @@ def grouped_matmul_ref(x, w, group_sizes, *, block_rows: int = 128):
         return None, xt @ jax.lax.dynamic_index_in_dim(w, g, keepdims=False)
 
     _, yb = jax.lax.scan(body, None, (xb, tile_gid))
-    return yb.reshape(Np, F)[dst]
+    live = jnp.arange(N) < jnp.sum(group_sizes)
+    return jnp.where(live[:, None], yb.reshape(Np, F)[dst], 0)
 
 
 # --------------------------------------------------------------------------

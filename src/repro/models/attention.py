@@ -1,6 +1,15 @@
-"""Grouped-query attention: parameter init, train/prefill apply (delegating
-the score/softmax/value contraction to ``repro.kernels.ops.attention``), and
+"""Grouped-query and multi-head latent attention: parameter init,
+train/prefill apply (delegating the score/softmax/value contraction to
+``repro.kernels.ops.attention``), and, for grouped-query attention,
 single-token decode against a KV cache.
+
+Latent attention (``cfg.kv_lora_rank`` > 0, DeepSeek-V2/V3 without query
+compression): ``q = x W_q`` per head ``[nope | rope]``; ``[c_kv, k_rope] =
+x W_kv_a``, ``c_kv`` RMS-normed; ``[k_nope | v] = c_kv W_kv_b`` per head; the
+rope parts rotate by adjacent pairs, ``k_rope`` is one head shared by all;
+scores over the whole ``head_dim``-wide query and key; values
+``v_head_dim`` wide.  Its train/prefill path is the only one: a latent
+cache for decode does not exist yet.
 
 Cache layouts (per layer):
   * full   — k/v ``[B, S, KV, hd]`` plus ``pos [B, S]`` (position held by each
@@ -15,10 +24,46 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops as kops
-from repro.models.embeddings import apply_rope
+from repro.models.embeddings import apply_norm, apply_rope, rotate_adjacent
+
+# DeepSeek's RMSNorm on the key/value latent keeps its class default
+MLA_LATENT_EPS = 1e-6
+
+
+def init_mla(key, cfg: ModelConfig, dtype=jnp.float32):
+    d, H, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
+    r, rr, hv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    nope = hd - rr
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": jax.random.normal(kq, (d, H * hd), dtype) * d ** -0.5,
+        "w_kv_a": jax.random.normal(ka, (d, r + rr), dtype) * d ** -0.5,
+        "kv_norm": {"scale": jnp.ones((r,), jnp.float32)},
+        "w_kv_b": jax.random.normal(kb, (r, H * (nope + hv)), dtype) * r ** -0.5,
+        "wo": jax.random.normal(ko, (H * hv, d), dtype) * (H * hv) ** -0.5,
+    }
+
+
+def _mla_qkv(cfg: ModelConfig, p, x, positions):
+    """q, k [B, S, H, head_dim] and v [B, S, H, v_head_dim]."""
+    B, S = x.shape[:2]
+    H, hd, rr = cfg.n_heads, cfg.head_dim, cfg.qk_rope_head_dim
+    r, nope = cfg.kv_lora_rank, hd - rr
+    q = (x @ p["wq"]).reshape(B, S, H, hd)
+    kv_a = x @ p["w_kv_a"]
+    c = apply_norm(cfg, p["kv_norm"], kv_a[..., :r], eps=MLA_LATENT_EPS)
+    kv = (c @ p["w_kv_b"]).reshape(B, S, H, -1)
+    k_rope = rotate_adjacent(kv_a[..., None, r:], positions, cfg.rope_base)
+    q = jnp.concatenate([q[..., :nope], rotate_adjacent(
+        q[..., nope:], positions, cfg.rope_base)], axis=-1)
+    k = jnp.concatenate([kv[..., :nope],
+                         jnp.broadcast_to(k_rope, (B, S, H, rr))], axis=-1)
+    return q, k, kv[..., nope:]
 
 
 def init_attention(key, cfg: ModelConfig, *, cross: bool = False, dtype=jnp.float32):
+    if cfg.kv_lora_rank:
+        return init_mla(key, cfg, dtype=dtype)
     d, hd = cfg.d_model, cfg.head_dim
     H, KV = cfg.n_heads, cfg.n_kv_heads
     kq, kk, kv, ko = jax.random.split(key, 4)
@@ -51,18 +96,26 @@ def _project_qkv(cfg: ModelConfig, p, xq, xkv):
     return q, k, v
 
 
+@jax.named_scope("attention")
 def attend(cfg: ModelConfig, p, x, positions, *, window: int | None, causal=True,
            x_kv=None, kv_positions=None, impl="auto", return_kv: bool = False):
     """Train/prefill attention.  ``x``: [B, S, d].  Returns [B, S, d]
     (and, with ``return_kv``, the rotated K/V for cache emission)."""
-    xkv = x if x_kv is None else x_kv
-    q, k, v = _project_qkv(cfg, p, x, xkv)
-    if x_kv is None:  # self attention gets RoPE
-        q = apply_rope(cfg, q, positions)
-        k = apply_rope(cfg, k, positions if kv_positions is None else kv_positions)
+    if cfg.kv_lora_rank:
+        if return_kv or x_kv is not None:
+            raise NotImplementedError(
+                "latent attention has no cache or cross-attention path")
+        q, k, v = _mla_qkv(cfg, p, x, positions)
+    else:
+        xkv = x if x_kv is None else x_kv
+        q, k, v = _project_qkv(cfg, p, x, xkv)
+        if x_kv is None:  # self attention gets RoPE
+            q = apply_rope(cfg, q, positions)
+            k = apply_rope(cfg, k, positions if kv_positions is None
+                           else kv_positions)
     o = kops.attention(q, k, v, causal=causal, window=window, impl=impl)
     B, S = x.shape[:2]
-    out = o.reshape(B, S, cfg.n_heads * cfg.head_dim) @ p["wo"]
+    out = o.reshape(B, S, -1) @ p["wo"]
     if return_kv:
         return out, (k.astype(jnp.bfloat16), v.astype(jnp.bfloat16))
     return out

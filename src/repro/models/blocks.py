@@ -12,6 +12,8 @@ implementation masks with it directly, so hybrid stacks stay scannable.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
@@ -70,6 +72,14 @@ def init_layer(key, cfg: ModelConfig, kind: str, dtype=jnp.float32):
         p["ssm"] = init_ssm(ks[4], cfg, dtype=dtype)
         p["norm_h"] = init_norm(cfg, d)
     return p
+
+
+def dense_prefix(cfg: ModelConfig) -> ModelConfig:
+    """The configuration of the leading dense layers (``cfg.dense_layers``):
+    the same attention, an MLP of their own width, no experts."""
+    return dataclasses.replace(cfg, family="dense", moe=None,
+                               d_ff=cfg.dense_layers_d_ff,
+                               n_layers=cfg.dense_layers, dense_layers=0)
 
 
 def init_stack(key, cfg: ModelConfig, n_layers: int, kind: str, dtype=jnp.float32):

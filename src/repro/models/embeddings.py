@@ -68,17 +68,30 @@ def apply_rope(cfg: ModelConfig, x, positions):
     sin = jnp.sin(ang)[..., None, :].astype(x.dtype)
     rot, rest = x[..., :n_rot], x[..., n_rot:]
     if cfg.rope == "2d-partial":
-        # interleaved pairing (x0,x1),(x2,x3),... — ChatGLM convention
-        x1, x2 = rot[..., 0::2], rot[..., 1::2]
-        r1 = x1 * cos - x2 * sin
-        r2 = x2 * cos + x1 * sin
-        rot = jnp.stack([r1, r2], axis=-1).reshape(rot.shape)
+        rot = _rotate_adjacent(rot, cos, sin)
     else:
         # half-split pairing (x_i, x_{i+n/2}) — Llama convention
         half = n_rot // 2
         x1, x2 = rot[..., :half], rot[..., half:]
         rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return jnp.concatenate([rot, rest], axis=-1) if rest.shape[-1] else rot
+
+
+def _rotate_adjacent(x, cos, sin):
+    """Interleaved pairing (x0,x1),(x2,x3),... — the ChatGLM and DeepSeek
+    convention."""
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    r = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return r.reshape(x.shape)
+
+
+def rotate_adjacent(x, positions, base: float):
+    """Rotate every dimension of x [..., S, heads, n] by adjacent pairs
+    (MLA's rotated query and key parts); positions broadcast to [..., S]."""
+    ang = _angles(positions, x.shape[-1], base)
+    cos = jnp.cos(ang)[..., None, :].astype(x.dtype)
+    sin = jnp.sin(ang)[..., None, :].astype(x.dtype)
+    return _rotate_adjacent(x, cos, sin)
 
 
 # --------------------------------------------------------------------------

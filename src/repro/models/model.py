@@ -40,9 +40,14 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32):
     if cfg.family == "ssm":
         p["layers"] = blocks.init_xlstm_layers(ks[1], cfg, dtype=dtype)
     else:
-        p["layers"] = blocks.init_stack(ks[1], cfg, cfg.n_layers,
+        p["layers"] = blocks.init_stack(ks[1], cfg,
+                                        cfg.n_layers - cfg.dense_layers,
                                         "xdecoder" if cfg.is_encoder_decoder else "decoder",
                                         dtype=dtype)
+    if cfg.dense_layers:
+        p["dense_layers"] = blocks.init_stack(
+            ks[7], blocks.dense_prefix(cfg), cfg.dense_layers, "decoder",
+            dtype=dtype)
     if cfg.is_encoder_decoder:
         p["encoder"] = blocks.init_stack(ks[2], cfg, cfg.encoder_layers, "encoder",
                                          dtype=dtype)
@@ -97,6 +102,11 @@ def backbone(cfg: ModelConfig, params, batch, *, use_window: bool = False,
         h, aux = blocks.apply_xlstm_layers(cfg, params["layers"], x)
     else:
         windows = blocks.layer_windows(cfg, S, use_window)
+        if cfg.dense_layers:
+            x, _ = blocks.apply_stack(
+                blocks.dense_prefix(cfg), params["dense_layers"], x, positions,
+                windows[:cfg.dense_layers], train=train, impl=impl)
+            windows = windows[cfg.dense_layers:]
         h, aux = blocks.apply_stack(cfg, params["layers"], x, positions, windows,
                                     train=train, impl=impl)
     return apply_norm(cfg, params["final_norm"], h), aux
@@ -198,7 +208,8 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
     total = sum(math.prod(l.shape) for l in jax.tree_util.tree_leaves(shapes))
     if active_only and cfg.moe is not None:
         m = cfg.moe
-        expert_params = 3 * m.n_experts * cfg.d_model * cfg.d_ff * cfg.n_layers
+        expert_params = (3 * m.n_held * cfg.d_model * cfg.d_ff
+                         * (cfg.n_layers - cfg.dense_layers))
         inactive = expert_params * (1 - m.top_k / m.n_experts)
         total -= int(inactive)
     return int(total)

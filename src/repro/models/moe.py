@@ -1,8 +1,10 @@
 """Mixture-of-experts block with top-k routing and two dispatch modes.
 
-Routing is shared (``route``): top-k over a softmax router, gate weights
-renormalized over the chosen k.  What differs is how chosen tokens reach
-their experts:
+Routing is shared (``route``): top-k over a softmax router (Switch
+auxiliary loss), or DeepSeek-V3's sigmoid router with a selection bias
+(``MoEConfig.scoring``); gate weights renormalized over the chosen k and
+scaled by ``routed_scale``.  What differs is how chosen tokens reach their
+experts:
 
 * ``capacity`` — padded scatter dispatch into an ``[E, C, d]`` buffer with
   ``C = ceil(T · top_k · capacity_factor / E)`` at train time (over-capacity
@@ -13,24 +15,30 @@ their experts:
   a sharded expert axis into all-to-all style collectives.
 
 * ``sorted`` — dropless sort-based dispatch: the flat ``[T·k]`` (token,
-  expert) assignments are argsorted by expert id, per-expert segment sizes
-  come from a bincount, the expert MLP runs as a ragged grouped GEMM over
-  the sorted ``[T·k, d]`` buffer (``kernels/ops.py::grouped_matmul`` — a
-  blocked-scan jnp reference on CPU/GPU, a tile-aligned scalar-prefetch
-  Pallas kernel on TPU), and a segment-aware scatter-add combines the
-  results.  No ``E``-fold padding:
-  at ``C = T`` the capacity buffer is ``E/top_k``-fold oversized in
-  expectation (64× on arctic-480b), which is exactly the waste this path
-  removes from the eval/decode hot path.
+  expert) assignments are argsorted by held expert id (assignments to
+  experts not held here sort last), per-expert segment sizes come from a
+  bincount, the expert MLP runs as a ragged grouped GEMM over the leading
+  ``T·min(k, E_held)`` rows of the sorted buffer — the static bound on the
+  rows routed here — (``kernels/ops.py::grouped_matmul`` — a blocked-scan
+  jnp reference on CPU/GPU, tile-aligned scalar-prefetch Pallas kernels
+  with a custom VJP on TPU), and a segment-aware scatter-add combines the
+  results.  No ``E``-fold padding: at ``C = T`` the capacity buffer is
+  ``E/top_k``-fold oversized in expectation (64× on arctic-480b).
 
-Training always uses ``capacity``; eval/decode use ``cfg.moe.dispatch``
-(default ``"sorted"``; ``"capacity"`` keeps the old dropless C = T path).
+Training and eval/decode alike use ``cfg.moe.dispatch`` (default
+``"sorted"``, dropless, as DeepSeek-V3 trains); ``"capacity"`` keeps the
+capacity-factor path for training and the dropless C = T path at eval.
 Both eval modes see bitwise-identical routing decisions — only the
 dispatch plumbing differs (``benchmarks/run.py --only moe_dispatch``
 measures the wall-clock and buffer-bytes gap).
 
-Arctic-style ``dense_residual`` adds an always-on MLP branch next to the
-experts.
+The held share (``MoEConfig.held_offset``/``n_held``) is expert
+parallelism without its exchange: the layer allocates only its own
+experts, routes every token over all of them, and returns its experts'
+part of the result; what the absent experts add is left out.
+``dense_residual`` adds an always-on, unscaled MLP of its own width beside
+the experts: Arctic's dense residual, or DeepSeek's shared experts as one
+SwiGLU of their summed width.
 """
 from __future__ import annotations
 
@@ -40,7 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.kernels import ops
+from repro.kernels import moe_dispatch, ops
 from repro.models.mlp import apply_mlp, init_mlp
 
 
@@ -55,18 +63,41 @@ def _constrain(x, spec):
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
     m = cfg.moe
-    d, ff, E = cfg.d_model, cfg.d_ff, m.n_experts
+    d, ff, E = cfg.d_model, cfg.d_ff, m.n_held
     kr, k1, k2, k3, kd = jax.random.split(key, 5)
     s = d ** -0.5
     p = {
-        "router": jax.random.normal(kr, (d, E), jnp.float32) * s,
+        "router": jax.random.normal(kr, (d, m.n_experts), jnp.float32) * s,
         "w_gate": jax.random.normal(k1, (E, d, ff), dtype) * s,
         "w_up": jax.random.normal(k2, (E, d, ff), dtype) * s,
         "w_down": jax.random.normal(k3, (E, ff, d), dtype) * ff ** -0.5,
     }
+    if m.scoring == "sigmoid":
+        p["select_bias"] = jnp.zeros((m.n_experts,), jnp.float32)
     if m.dense_residual:
         p["dense"] = init_mlp(kd, cfg, d_ff=m.dense_d_ff, dtype=dtype)
     return p
+
+
+def held_rows(cfg: ModelConfig, n_tokens: int) -> int:
+    """Static bound on the rows routed to the held experts: each token
+    reaches at most min(k, E_held) of them."""
+    m = cfg.moe
+    return n_tokens * min(m.top_k, m.n_held)
+
+
+def share_geometry(cfg: ModelConfig, n_tokens: int) -> dict:
+    """What the sorted dispatch of one MoE layer launches for ``n_tokens``,
+    from shapes alone: the static row bound, the rows expected live when
+    the router spreads tokens evenly (T·k·E_held/E), and the forward grouped
+    GEMM's tiles and grid (``grid[1]`` row tiles, of which about
+    ``expected_rows / bm + E_held`` are live)."""
+    m = cfg.moe
+    R = held_rows(cfg, n_tokens)
+    g = moe_dispatch.launch_geometry(R, cfg.d_model, m.n_held, cfg.d_ff)
+    return {"row_bound": R,
+            "expected_rows": n_tokens * m.top_k * m.n_held / m.n_experts,
+            "bm": g["bm"], "padded_rows": g["Np"], "grid": g["grid"]}
 
 
 def capacity(cfg: ModelConfig, n_tokens: int, *, train: bool = True) -> int:
@@ -87,12 +118,12 @@ def dispatch_buffer_bytes(cfg: ModelConfig, n_tokens: int, *,
                           dtype=jnp.float32) -> int:
     """Bytes of the per-layer dispatch buffer a forward pass of ``n_tokens``
     allocates under each mode — the quantity the moe_dispatch benchmark and
-    scripts/mem_pass.py account for.  ``sorted`` gathers [T·k, d];
-    ``capacity`` gathers [E, C, d]."""
+    scripts/mem_pass.py account for.  ``sorted`` gathers [T·min(k,
+    E_held), d] (``held_rows``); ``capacity`` gathers [E, C, d]."""
     m = cfg.moe
     itemsize = jnp.dtype(dtype).itemsize
     if mode == "sorted":
-        return n_tokens * m.top_k * cfg.d_model * itemsize
+        return held_rows(cfg, n_tokens) * cfg.d_model * itemsize
     if mode == "capacity":
         return (m.n_experts * capacity(cfg, n_tokens, train=train)
                 * cfg.d_model * itemsize)
@@ -109,14 +140,28 @@ def tokens_per_forward(spec) -> int:
 
 
 def route(cfg: ModelConfig, p, xf):
-    """Shared routing decision.  xf: [T, d] -> (top_g [T, k] fp32 renormed,
-    top_e [T, k] int32, gates [T, E] fp32).  Both dispatch modes consume
-    exactly this — the modes are bitwise-identical in WHAT they route and
-    differ only in how tokens reach the experts."""
-    logits = (xf @ p["router"].astype(xf.dtype)).astype(jnp.float32)  # [T, E]
-    gates = jax.nn.softmax(logits, axis=-1)
-    top_g, top_e = jax.lax.top_k(gates, cfg.moe.top_k)  # [T, k]
-    top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)
+    """Shared routing decision.  xf: [T, d] -> (top_g [T, k] fp32 gate
+    weights, top_e [T, k] int32 expert ids over all E, gates [T, E] fp32).
+    Both dispatch modes consume exactly this — the modes are
+    bitwise-identical in WHAT they route and differ only in how tokens
+    reach the experts.
+
+    softmax: top-k of the softmax.  sigmoid (``noaux_tc``, one group):
+    scores s = sigmoid(x W_r) in fp32, top-k of s + select_bias, weights the
+    unbiased s of the chosen experts.  Either way the weights are
+    renormalized over the k and scaled by ``routed_scale``."""
+    m = cfg.moe
+    if m.scoring == "sigmoid":
+        logits = jnp.dot(xf.astype(jnp.float32), p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        gates = jax.nn.sigmoid(logits)
+        _, top_e = jax.lax.top_k(gates + p["select_bias"], m.top_k)
+        top_g = jnp.take_along_axis(gates, top_e, axis=-1)
+    else:
+        logits = (xf @ p["router"].astype(xf.dtype)).astype(jnp.float32)
+        gates = jax.nn.softmax(logits, axis=-1)
+        top_g, top_e = jax.lax.top_k(gates, m.top_k)  # [T, k]
+    top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True) * m.routed_scale
     return top_g, top_e, gates
 
 
@@ -157,32 +202,39 @@ def _dispatch_capacity(cfg: ModelConfig, p, xf, top_g, top_e, C: int):
 
 def _dispatch_sorted(cfg: ModelConfig, p, xf, top_g, top_e, *,
                      impl: str = "auto"):
-    """Dropless sort-based dispatch: argsort the [T·k] assignments by expert,
-    grouped GEMM over the sorted [T·k, d] buffer, segment scatter-add back."""
+    """Dropless sort-based dispatch over the held experts: argsort the
+    [T·k] assignments by held expert (the rest sort last), grouped GEMM
+    over the leading ``held_rows`` rows of the sorted buffer, segment
+    scatter-add back.  Rows past the held experts' count are dead: the
+    grouped GEMM gives them 0 and their gate weight is 0."""
     m = cfg.moe
     T, d = xf.shape
-    E, k = m.n_experts, m.top_k
+    k, E = m.top_k, m.n_held
 
-    e_flat = top_e.reshape(-1)                                # [T*k]
-    t_flat = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-    g_flat = top_g.reshape(-1)
-    order = jnp.argsort(e_flat)                               # stable
-    src = t_flat[order]                                       # token per row
-    counts = jnp.bincount(e_flat, length=E)                   # segment sizes
+    local = top_e.reshape(-1) - m.held_offset                 # [T*k]
+    held = (local >= 0) & (local < E)
+    key = jnp.where(held, local, E)
+    R = held_rows(cfg, T)
+    order = jnp.argsort(key)[:R]                              # stable
+    src = order // k                                          # token per row
+    counts = jnp.bincount(key, length=E + 1)[:E]              # segment sizes
+    live = jnp.arange(R) < jnp.sum(counts)
 
-    xs = _constrain(jnp.take(xf, src, axis=0), ("data", None))  # [T·k, d]
+    xs = _constrain(jnp.take(xf, src, axis=0), ("data", None))  # [R, d]
     wdt = jnp.promote_types(xs.dtype, p["w_gate"].dtype)
     xs = xs.astype(wdt)
     gm = lambda a, w: ops.grouped_matmul(a, w.astype(wdt), counts, impl=impl)
     h = _constrain(gm(xs, p["w_gate"]), ("data", "model"))
     u = _constrain(gm(xs, p["w_up"]), ("data", "model"))
-    ys = gm(jax.nn.silu(h) * u, p["w_down"])                  # [T·k, d]
+    ys = gm(jax.nn.silu(h) * u, p["w_down"])                  # [R, d]
     ys = _constrain(ys, ("data", None))
-    ys = ys * g_flat[order][:, None].astype(ys.dtype)
+    g = jnp.where(live, top_g.reshape(-1)[order], 0.0)
+    ys = ys * g[:, None].astype(ys.dtype)
 
     return jnp.zeros((T, d), ys.dtype).at[src].add(ys)        # combine
 
 
+@jax.named_scope("moe")
 def apply_moe(cfg: ModelConfig, p, x, *, train: bool = False,
               impl: str = "auto"):
     """x: [B, S, d] -> (out [B, S, d], aux_loss scalar)."""
@@ -194,22 +246,26 @@ def apply_moe(cfg: ModelConfig, p, x, *, train: bool = False,
 
     top_g, top_e, gates = route(cfg, p, xf)
 
-    mode = "capacity" if train else m.dispatch
-    if mode == "capacity":
+    if m.dispatch == "capacity":
         out = _dispatch_capacity(cfg, p, xf, top_g, top_e,
                                  capacity(cfg, T, train=train))
     else:
         out = _dispatch_sorted(cfg, p, xf, top_g, top_e, impl=impl)
     out = out.reshape(B, S, d).astype(x.dtype)
 
-    # Switch-style load-balance auxiliary loss.  ``ce`` counts the dispatched
-    # fraction over ALL k choices (normalized by k) so top-2 archs (dbrx,
-    # arctic) balance both slots; at k = 1 this reduces exactly to the
-    # classic top-1 count (pinned by tests/test_moe_dispatch.py).
-    me = jnp.mean(gates, axis=0)  # mean router prob per expert
-    ce = jnp.mean(jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=1),
-                  axis=0) / k
-    aux = E * jnp.sum(me * ce)
+    if m.scoring == "sigmoid":
+        # the selection bias is the balancing mechanism: no auxiliary loss
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        # Switch-style load-balance auxiliary loss.  ``ce`` counts the
+        # dispatched fraction over ALL k choices (normalized by k) so top-2
+        # archs (dbrx, arctic) balance both slots; at k = 1 this reduces
+        # exactly to the classic top-1 count (pinned by
+        # tests/test_moe_dispatch.py).
+        me = jnp.mean(gates, axis=0)  # mean router prob per expert
+        ce = jnp.mean(jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32),
+                              axis=1), axis=0) / k
+        aux = E * jnp.sum(me * ce)
 
     if m.dense_residual:
         out = out + apply_mlp(cfg, p["dense"], x)

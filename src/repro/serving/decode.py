@@ -33,9 +33,18 @@ def _layer_params(params_layers, i: int):
 # --------------------------------------------------------------------------
 # cache construction
 # --------------------------------------------------------------------------
+def _refuse_latent(cfg: ModelConfig):
+    if cfg.kv_lora_rank or cfg.dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention (MLA) and a dense layer prefix "
+            "have no decode path yet; the latent cache is a serving change "
+            "of its own")
+
+
 def init_cache(cfg: ModelConfig, B: int, S: int, *, use_window: bool = True,
                dtype=jnp.bfloat16):
     """Cache pytree for a decode session of maximum length S."""
+    _refuse_latent(cfg)
     if cfg.family == "ssm":
         layers = []
         for kind in blocks.xlstm_layer_kinds(cfg):
@@ -99,6 +108,7 @@ def serve_step(cfg: ModelConfig, params, cache, tokens, positions, *,
 
     Returns (logits [B, vocab], score_logit [B], new_cache).
     """
+    _refuse_latent(cfg)
     x = embed(params["embed"], tokens)  # [B, 1, d]
     new_layers = []
     if cfg.family == "ssm":

@@ -41,6 +41,10 @@ HOST_SPANS = (WINDOW, SAMPLE, DISPATCH, SYNC, EVAL, CKPT, STAGE_END)
 # unpacking) and the stage boundary
 SCOPES = ("window", "local_step", "model", "auc_loss", "update", "dual_step",
           "average", "stage_end")
+# scopes inside the model, opened where a model has the layer: attention
+# (``models/attention.attend``) and the mixture of experts
+# (``models/moe.apply_moe``); an MLP model has neither
+MODEL_SCOPES = ("attention", "moe")
 
 # a path component names a scope bare (``update``) or wrapped in the
 # transforms applied to it (``vmap(transpose(jvp(model)))``); a ``jit(...)``
@@ -50,10 +54,10 @@ _COMPONENT = re.compile(r"(?:(?!jit\()\w+\()*(\w+)\)*")
 
 def scope_of(op_name: str) -> str:
     """The deepest component of an HLO op's ``op_name`` path that is one of
-    ``SCOPES``, or ``"other"``."""
+    ``SCOPES`` or ``MODEL_SCOPES``, or ``"other"``."""
     for part in reversed(op_name.split("/")):
         m = _COMPONENT.fullmatch(part)
-        if m and m.group(1) in SCOPES:
+        if m and m.group(1) in SCOPES + MODEL_SCOPES:
             return m.group(1)
     return "other"
 

@@ -96,3 +96,28 @@ def test_cross_attention_no_causal():
     out = ref.attention_chunked(q, k, v, causal=False, chunk=32)
     exp = ref.attention_full(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["full", "chunked"])
+def test_value_head_narrower_than_query_key(impl):
+    """Latent attention's widths: query/key heads of 192, value heads of
+    128; scores scaled by 192^-0.5.  Against a per-head softmax written out
+    in numpy (float64)."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(13), 3)
+    S, H = 64, 2
+    q = jax.random.normal(kq, (1, S, H, 192))
+    k = jax.random.normal(kk, (1, S, H, 192))
+    v = jax.random.normal(kv, (1, S, H, 128))
+    if impl == "full":
+        out = ref.attention_full(q, k, v, causal=True)
+    else:
+        out = ref.attention_chunked(q, k, v, causal=True, chunk=16)
+    assert out.shape == (1, S, H, 128)
+    qn, kn, vn = (np.asarray(a, np.float64)[0] for a in (q, k, v))
+    s = np.einsum("qhd,khd->hqk", qn, kn) / np.sqrt(192)
+    s = np.where(np.tril(np.ones((S, S), bool)), s, -np.inf)
+    w = np.exp(s - s.max(-1, keepdims=True))
+    w /= w.sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(out[0]),
+                               np.einsum("hqk,khd->qhd", w, vn),
+                               atol=2e-5, rtol=2e-5)

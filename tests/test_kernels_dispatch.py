@@ -101,8 +101,11 @@ def test_explicit_pallas_grouped_matmul_interprets_off_tpu():
     w = jax.random.normal(key, (3, 4, 8))
     gs = jnp.asarray([2, 3, 1], jnp.int32)
     got = ops.grouped_matmul(x, w, gs, impl="pallas")
+    # the kernel multiplies in one bf16 pass: the reference gets the same
+    # bf16-rounded operands, whose products fp32 holds exactly
+    bf = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref.grouped_matmul_ref(x, w, gs)),
+        np.asarray(got), np.asarray(ref.grouped_matmul_ref(bf(x), bf(w), gs)),
         atol=1e-5)
 
 

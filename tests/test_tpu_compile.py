@@ -202,3 +202,38 @@ def test_grouped_matmul_compiles_for_v5e(one_chip):
     sizes = _spec(one_chip, (2,), jnp.int32)
     txt = _compiled_text(grouped_matmul, x, w, sizes)
     _assert_named(txt, "grouped_matmul")
+
+
+def test_grouped_matmul_backward_compiles_at_moonlight_widths(one_chip):
+    """The dropless MoE's three expert products and their backward at the
+    published widths (hidden 2048, expert 1408, 8 held experts) on fewer
+    rows: the forward/dx kernel and the dw kernel compile within the
+    VMEM they ask for, the transposed-LHS contraction of dw included, and
+    ``grouped_matmul_roofline``'s pattern finds both and nothing else."""
+    sys.path.insert(0, str(CHIP_BENCH))
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "grouped_matmul_roofline",
+            CHIP_BENCH / "metrics" / "grouped_matmul_roofline.py")
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.remove(str(CHIP_BENCH))
+
+    def expert_mlp(x, wg, wu, wd, sizes):
+        h = grouped_matmul(x, wg, sizes)
+        u = grouped_matmul(x, wu, sizes)
+        return jnp.sum(grouped_matmul(jax.nn.silu(h) * u, wd, sizes))
+
+    d, ff, E = 2048, 1408, 8
+    txt = _compiled_text(
+        jax.grad(expert_mlp, argnums=(0, 1, 2, 3)),
+        _spec(one_chip, (1024, d)), _spec(one_chip, (E, d, ff)),
+        _spec(one_chip, (E, d, ff)), _spec(one_chip, (E, ff, d)),
+        _spec(one_chip, (E,), jnp.int32))
+    calls = _kernel_calls(txt)
+    assert sorted({re.sub(r"(\.\d+)+$", "", c) for c in calls}) == \
+        ["grouped_matmul", "grouped_matmul_wgrad"], calls
+    names = re.findall(r"^\s*(?:ROOT )?%(\S+) = ", txt, re.M)
+    assert sorted(n for n in names if re.match(reader.KERNEL, n)) == \
+        sorted(calls)

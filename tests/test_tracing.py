@@ -183,6 +183,29 @@ def test_shard_map_programs_carry_every_scope():
     ("jit(body)/window/average/psum", "average"),
     ("jit(f)/jit(update)/mul", "other"),
     ("st['params']['w']", "other"),
+    ("jit(body)/window/while/body/closed_call/local_step/"
+     "vmap(transpose(jvp(model)))/while/body/checkpoint/attention/dot_general",
+     "attention"),
+    ("jit(body)/window/while/body/closed_call/local_step/vmap(jvp(model))/"
+     "while/body/checkpoint/moe/jit(grouped_matmul)/pallas_call", "moe"),
 ])
 def test_scope_of(op_name, scope):
     assert tracing.scope_of(op_name) == scope
+
+
+def test_moe_mla_program_carries_model_scopes():
+    """A latent-attention MoE model's CoDA window splits the model's ops
+    into the ``attention`` and ``moe`` scopes, inside ``model``; the model
+    scopes stay out of ``SCOPES``, which every model's window carries."""
+    from repro.configs import get_smoke_config
+
+    assert not set(tracing.MODEL_SCOPES) & set(tracing.SCOPES)
+    mcfg = get_smoke_config("moonlight-16b-a3b")
+    ccfg = coda.CoDAConfig(n_workers=1, p_pos=0.7)
+    key = jax.random.PRNGKey(2)
+    st = coda.init_state(key, mcfg, ccfg)
+    exe = coda.make_executor(mcfg, ccfg, "vmap", donate=False)
+    wb = {"tokens": jax.random.randint(key, (1, 1, 2, 8), 0, mcfg.vocab_size),
+          "labels": jnp.asarray([[[1.0, 0.0]]])}
+    window = exe._wstep.lower(st, wb, jnp.float32(0.1)).compile().as_text()
+    assert {"model", "attention", "moe"} <= _scopes(window)
