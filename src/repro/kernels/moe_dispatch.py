@@ -6,7 +6,9 @@ for rows already sorted by expert id — the ragged core of the sorted
 dispatch path (models/moe.py) — without materializing the [E, C, d]
 capacity buffer.  Rows past ``sum(group_sizes)`` belong to no expert held
 here and give 0: the dispatch hands over a buffer at a static row bound of
-which only the leading rows are routed to this chip's experts.
+which only the leading rows are routed to this chip's experts — the tight
+bound (``models/moe.tight_rows``, about twice the rows an even router
+sends here) while those rows fit in it, else ``T·min(k, E_held)``.
 
 Kernel strategy (MegaBlocks-style tile alignment + scalar prefetch):
 

@@ -18,11 +18,15 @@ experts:
   expert) assignments are argsorted by held expert id (assignments to
   experts not held here sort last), per-expert segment sizes come from a
   bincount, the expert MLP runs as a ragged grouped GEMM over the leading
-  ``T·min(k, E_held)`` rows of the sorted buffer — the static bound on the
-  rows routed here — (``kernels/ops.py::grouped_matmul`` — a blocked-scan
-  jnp reference on CPU/GPU, tile-aligned scalar-prefetch Pallas kernels
-  with a custom VJP on TPU), and a segment-aware scatter-add combines the
-  results.  No ``E``-fold padding: at ``C = T`` the capacity buffer is
+  rows of the sorted buffer (``kernels/ops.py::grouped_matmul`` — a
+  blocked-scan jnp reference on CPU/GPU, tile-aligned scalar-prefetch
+  Pallas kernels with a custom VJP on TPU), and a segment-aware
+  scatter-add combines the results.  How many leading rows is a static
+  bound: ``T·min(k, E_held)`` (``held_rows``) can never be exceeded, but a
+  held share routes about ``T·k·E_held/E`` rows, so where ``tight_rows``
+  (``ROW_SLACK`` times that) is tighter, the layer runs at it whenever the
+  routed rows fit and at the full bound otherwise — the same numbers
+  either way.  No ``E``-fold padding: at ``C = T`` the capacity buffer is
   ``E/top_k``-fold oversized in expectation (64× on arctic-480b).
 
 Training and eval/decode alike use ``cfg.moe.dispatch`` (default
@@ -42,14 +46,23 @@ SwiGLU of their summed width.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.kernels import moe_dispatch, ops
 from repro.models.mlp import apply_mlp, init_mlp
+
+# The tight row bound's slack over the rows an even router sends to the
+# held experts (T·k·E_held/E): Moonlight's share routed at most 1.2x that
+# to one layer on a measured batch, and a batch that routes more than the
+# slack runs at the full bound, so the slack costs time, never results.
+ROW_SLACK = 2
 
 
 def _constrain(x, spec):
@@ -86,16 +99,29 @@ def held_rows(cfg: ModelConfig, n_tokens: int) -> int:
     return n_tokens * min(m.top_k, m.n_held)
 
 
+def tight_rows(cfg: ModelConfig, n_tokens: int) -> int:
+    """The row bound the sorted dispatch runs at while the routed rows fit:
+    ``ROW_SLACK`` times the rows an even router sends to the held experts,
+    rounded up to the grouped GEMM's row tile, or ``held_rows`` where that
+    is no tighter (every expert held, or few tokens)."""
+    m = cfg.moe
+    rows = -(-ROW_SLACK * n_tokens * m.top_k * m.n_held // m.n_experts)
+    bm = moe_dispatch.launch_geometry(rows, cfg.d_model, m.n_held,
+                                      cfg.d_ff)["bm"]
+    return min(held_rows(cfg, n_tokens), -(-rows // bm) * bm)
+
+
 def share_geometry(cfg: ModelConfig, n_tokens: int) -> dict:
     """What the sorted dispatch of one MoE layer launches for ``n_tokens``,
-    from shapes alone: the static row bound, the rows expected live when
+    from shapes alone: the static row bound, the tight bound it runs at
+    while the routed rows fit (``tight_rows``), the rows expected live when
     the router spreads tokens evenly (T·k·E_held/E), and the forward grouped
-    GEMM's tiles and grid (``grid[1]`` row tiles, of which about
-    ``expected_rows / bm + E_held`` are live)."""
+    GEMM's tiles and grid at the static bound (``grid[1]`` row tiles, of
+    which about ``expected_rows / bm + E_held`` are live)."""
     m = cfg.moe
     R = held_rows(cfg, n_tokens)
     g = moe_dispatch.launch_geometry(R, cfg.d_model, m.n_held, cfg.d_ff)
-    return {"row_bound": R,
+    return {"row_bound": R, "tight_rows": tight_rows(cfg, n_tokens),
             "expected_rows": n_tokens * m.top_k * m.n_held / m.n_experts,
             "bm": g["bm"], "padded_rows": g["Np"], "grid": g["grid"]}
 
@@ -118,8 +144,9 @@ def dispatch_buffer_bytes(cfg: ModelConfig, n_tokens: int, *,
                           dtype=jnp.float32) -> int:
     """Bytes of the per-layer dispatch buffer a forward pass of ``n_tokens``
     allocates under each mode — the quantity the moe_dispatch benchmark and
-    scripts/mem_pass.py account for.  ``sorted`` gathers [T·min(k,
-    E_held), d] (``held_rows``); ``capacity`` gathers [E, C, d]."""
+    scripts/mem_pass.py account for.  ``sorted`` gathers at most [T·min(k,
+    E_held), d] (``held_rows``; [``tight_rows``, d] while the routed rows
+    fit); ``capacity`` gathers [E, C, d]."""
     m = cfg.moe
     itemsize = jnp.dtype(dtype).itemsize
     if mode == "sorted":
@@ -200,38 +227,116 @@ def _dispatch_capacity(cfg: ModelConfig, p, xf, top_g, top_e, C: int):
         ye.reshape(E * C, d))  # combine
 
 
-def _dispatch_sorted(cfg: ModelConfig, p, xf, top_g, top_e, *,
-                     impl: str = "auto"):
-    """Dropless sort-based dispatch over the held experts: argsort the
-    [T·k] assignments by held expert (the rest sort last), grouped GEMM
-    over the leading ``held_rows`` rows of the sorted buffer, segment
-    scatter-add back.  Rows past the held experts' count are dead: the
-    grouped GEMM gives them 0 and their gate weight is 0."""
-    m = cfg.moe
+def _experts(R: int, impl: str, xf, w, top_g, order, counts):
+    """The held experts over the leading ``R`` rows of the sorted
+    assignments ``order`` ([T·k] flat (token, choice) indices, held experts
+    first), combined back into their tokens: [T, d].  Rows past the held
+    experts' count (``sum(counts)``) are dead: the grouped GEMM gives them 0
+    and their gate weight is 0."""
     T, d = xf.shape
-    k, E = m.top_k, m.n_held
-
-    local = top_e.reshape(-1) - m.held_offset                 # [T*k]
-    held = (local >= 0) & (local < E)
-    key = jnp.where(held, local, E)
-    R = held_rows(cfg, T)
-    order = jnp.argsort(key)[:R]                              # stable
+    k = top_g.shape[-1]
+    w_gate, w_up, w_down = w
+    order = order[:R]
     src = order // k                                          # token per row
-    counts = jnp.bincount(key, length=E + 1)[:E]              # segment sizes
     live = jnp.arange(R) < jnp.sum(counts)
 
     xs = _constrain(jnp.take(xf, src, axis=0), ("data", None))  # [R, d]
-    wdt = jnp.promote_types(xs.dtype, p["w_gate"].dtype)
+    wdt = jnp.promote_types(xs.dtype, w_gate.dtype)
     xs = xs.astype(wdt)
     gm = lambda a, w: ops.grouped_matmul(a, w.astype(wdt), counts, impl=impl)
-    h = _constrain(gm(xs, p["w_gate"]), ("data", "model"))
-    u = _constrain(gm(xs, p["w_up"]), ("data", "model"))
-    ys = gm(jax.nn.silu(h) * u, p["w_down"])                  # [R, d]
+    h = _constrain(gm(xs, w_gate), ("data", "model"))
+    u = _constrain(gm(xs, w_up), ("data", "model"))
+    ys = gm(jax.nn.silu(h) * u, w_down)                       # [R, d]
     ys = _constrain(ys, ("data", None))
     g = jnp.where(live, top_g.reshape(-1)[order], 0.0)
     ys = ys * g[:, None].astype(ys.dtype)
 
     return jnp.zeros((T, d), ys.dtype).at[src].add(ys)        # combine
+
+
+def _by_bound(fits, tight, full, *args):
+    """``tight(*args)`` where ``fits``, else ``full(*args)`` under the scope
+    ``moe_full_bound``; only the branch taken runs.  Under ``vmap`` (the
+    executor's worker axis) the batch takes one branch, the tight one only
+    where every member fits: a batched predicate would turn ``lax.cond``
+    into a select that runs both."""
+    def scoped_full(*a):
+        with jax.named_scope(tracing.FULL_BOUND):
+            return full(*a)
+
+    @custom_vmap
+    def run(fits, *args):
+        return jax.lax.cond(fits, tight, scoped_full, *args)
+
+    @run.def_vmap
+    def _(axis_size, in_batched, fits, *args):
+        axes = tuple(jax.tree.map(lambda b: 0 if b else None, b)
+                     for b in in_batched[1:])
+        batched = lambda f: jax.vmap(f, in_axes=axes, axis_size=axis_size)
+        out = jax.lax.cond(jnp.all(fits), batched(tight), batched(scoped_full),
+                           *args)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return run(fits, *args)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _bounded_experts(R_t: int, R: int, impl: str, xf, w, top_g, order,
+                     counts):
+    """``_experts`` at the tight bound ``R_t`` while the routed rows fit
+    (``sum(counts) <= R_t``), else at the full bound ``R``: the same result.
+    Its VJP saves the inputs alone and recomputes the branch taken, so no
+    buffer of either bound outlives the call (differentiating a
+    ``lax.cond`` would save both branches' residuals)."""
+    return _bounded_fwd(R_t, R, impl, xf, w, top_g, order, counts)[0]
+
+
+def _bounded_fwd(R_t, R, impl, xf, w, top_g, order, counts):
+    at = lambda R: functools.partial(_experts, R, impl)
+    out = _by_bound(jnp.sum(counts) <= R_t, at(R_t), at(R), xf, w, top_g,
+                    order, counts)
+    return out, (xf, w, top_g, order, counts)
+
+
+def _bounded_bwd(R_t, R, impl, res, dy):
+    xf, w, top_g, order, counts = res
+
+    def grads(R):
+        def f(xf, w, top_g, order, counts, dy):
+            _, vjp = jax.vjp(lambda xf, w, top_g: _experts(
+                R, impl, xf, w, top_g, order, counts), xf, w, top_g)
+            return vjp(dy)
+        return f
+
+    return (*_by_bound(jnp.sum(counts) <= R_t, grads(R_t), grads(R), xf, w,
+                       top_g, order, counts, dy), None, None)
+
+
+_bounded_experts.defvjp(_bounded_fwd, _bounded_bwd)
+
+
+def _dispatch_sorted(cfg: ModelConfig, p, xf, top_g, top_e, *,
+                     impl: str = "auto"):
+    """Dropless sort-based dispatch over the held experts: argsort the
+    [T·k] assignments by held expert (the rest sort last), grouped GEMM
+    over the leading rows of the sorted buffer, segment scatter-add back
+    (``_experts``).  The leading rows are ``tight_rows`` while the rows
+    routed here fit in them and ``held_rows`` otherwise; where the tight
+    bound is no tighter, ``held_rows`` alone, with no branch."""
+    m = cfg.moe
+    T = xf.shape[0]
+    E = m.n_held
+
+    local = top_e.reshape(-1) - m.held_offset                 # [T*k]
+    held = (local >= 0) & (local < E)
+    key = jnp.where(held, local, E)
+    order = jnp.argsort(key)                                  # stable
+    counts = jnp.bincount(key, length=E + 1)[:E]              # segment sizes
+    w = (p["w_gate"], p["w_up"], p["w_down"])
+    R, R_t = held_rows(cfg, T), tight_rows(cfg, T)
+    if R_t < R:
+        return _bounded_experts(R_t, R, impl, xf, w, top_g, order, counts)
+    return _experts(R, impl, xf, w, top_g, order, counts)
 
 
 @jax.named_scope("moe")

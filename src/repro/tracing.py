@@ -42,9 +42,12 @@ HOST_SPANS = (WINDOW, SAMPLE, DISPATCH, SYNC, EVAL, CKPT, STAGE_END)
 SCOPES = ("window", "local_step", "model", "auc_loss", "update", "dual_step",
           "average", "stage_end")
 # scopes inside the model, opened where a model has the layer: attention
-# (``models/attention.attend``) and the mixture of experts
-# (``models/moe.apply_moe``); an MLP model has neither
-MODEL_SCOPES = ("attention", "moe")
+# (``models/attention.attend``), the mixture of experts
+# (``models/moe.apply_moe``) and, inside it, the sorted dispatch's run at
+# its full row bound, taken only when more rows are routed to the held
+# experts than the tight bound holds; an MLP model has none
+FULL_BOUND = "moe_full_bound"
+MODEL_SCOPES = ("attention", "moe", FULL_BOUND)
 
 # a path component names a scope bare (``update``) or wrapped in the
 # transforms applied to it (``vmap(transpose(jvp(model)))``); a ``jit(...)``

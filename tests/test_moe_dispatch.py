@@ -6,7 +6,10 @@ drops (eval C = T is dropless by construction); (3) the grouped-GEMM Pallas
 kernel ≡ its blocked-scan jnp reference on ragged/empty/unaligned segments;
 (4) the all-k load-balance aux loss reduces to the classic top-1 count at
 k = 1 and actually counts both slots at k = 2; (5) the dispatch-buffer
-accounting the moe_dispatch benchmark reports."""
+accounting the moe_dispatch benchmark reports; (6) a held share's sorted
+dispatch at its tight row bound ≡ the full bound, values and gradients,
+with routing inside the tight bound and routing forced over it (the
+fallback), and its VJP saves nothing at the full bound."""
 import dataclasses
 
 import jax
@@ -202,3 +205,85 @@ def test_prefill_matches_parallel_scoring_moe():
     want = M.lm_logits(cfg, params, h[:, -1])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-3, rtol=2e-3)
+
+
+# A held share (experts 2 and 3 of 8, 3 per token) at T = 32: full bound
+# T·min(k, E_held) = 64 rows, tight bound 2 · T·k·E_held/E = 48 rows.
+HELD_T = 32
+
+
+def _held_cfg():
+    return ModelConfig(
+        name="tiny-held", family="moe", n_layers=1, d_model=16, n_heads=2,
+        n_kv_heads=2, d_ff=32, vocab_size=64,
+        moe=MoEConfig(n_experts=8, top_k=3, n_held=2, held_offset=2,
+                      scoring="sigmoid"))
+
+
+def _held_case(routing):
+    """Weights and input; ``over``: a selection bias that sends every token
+    to both held experts, more rows than the tight bound holds."""
+    cfg = _held_cfg()
+    kp, kx = jax.random.split(jax.random.PRNGKey(7))
+    p = moe.init_moe(kp, cfg)
+    if routing == "over":
+        p["select_bias"] = p["select_bias"].at[2:4].set(5.0)
+    x = jax.random.normal(kx, (1, HELD_T, cfg.d_model), jnp.float32)
+    return cfg, p, x
+
+
+def test_held_share_bounds():
+    cfg = _held_cfg()
+    assert moe.held_rows(cfg, HELD_T) == 64
+    assert moe.tight_rows(cfg, HELD_T) == 48
+    # every expert held: the tight bound is no tighter, one path
+    full = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, n_held=8, held_offset=0))
+    assert moe.tight_rows(full, HELD_T) == moe.held_rows(full, HELD_T)
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+@pytest.mark.parametrize("routing", ["inside", "over"])
+def test_tight_bound_matches_full_bound(monkeypatch, routing, impl):
+    """Output and every gradient (the input, the three expert weights, the
+    router through the gate weights) of the held share's dispatch at its
+    tight bound equal those of the full bound alone; routed over the tight
+    bound, the fallback gives them."""
+    cfg, p, x = _held_case(routing)
+    _, top_e, _ = moe.route(cfg, p, x.reshape(HELD_T, -1))
+    rows = int(jnp.sum((top_e >= 2) & (top_e < 4)))
+    assert (rows <= 48) == (routing == "inside"), rows
+    tang = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+
+    def value_and_grads():
+        f = lambda p, x: jnp.sum(
+            moe.apply_moe(cfg, p, x, train=True, impl=impl)[0] * tang)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(p, x)
+
+    got, g_got = value_and_grads()
+    monkeypatch.setattr(moe, "tight_rows", moe.held_rows)
+    want, g_want = value_and_grads()
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    names = [jax.tree_util.keystr(k) for k, _ in
+             jax.tree_util.tree_flatten_with_path(g_want)[0]]
+    for name, a, b in zip(names, jax.tree_util.tree_leaves(g_got),
+                          jax.tree_util.tree_leaves(g_want)):
+        scale = max(float(jnp.max(jnp.abs(b))), 1e-6)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-5 * scale, err_msg=name)
+    for w in ("router", "w_gate", "w_up", "w_down"):
+        assert float(jnp.max(jnp.abs(g_want[0][w]))) > 0, w
+
+
+@pytest.mark.parametrize("routing", ["inside", "over"])
+def test_tight_bound_saves_nothing_at_the_full_bound(routing):
+    """The dispatch's VJP keeps its inputs and the [T·k] routing arrays: no
+    saved array has the full bound's rows (64) or its padded grouped-GEMM
+    layout's (64 + 2 · 64), whichever bound the routing takes."""
+    cfg, p, x = _held_case(routing)
+    _, vjp = jax.vjp(lambda p, x: moe.apply_moe(cfg, p, x, train=True)[0],
+                     p, x)
+    saved = [a.shape for a in jax.tree_util.tree_leaves(vjp)]
+    assert saved
+    full = {64, 64 + 2 * 64}
+    assert not [s for s in saved if s and s[0] in full], saved

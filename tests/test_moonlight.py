@@ -5,7 +5,7 @@ sigmoid router with its selection bias, the shared experts, the dense
 prefix and the held expert share, values and gradients; the share-sum
 identity that ties the share to the uncut layer; the grouped GEMM's
 backward kernels (interpret mode) against the jnp reference; the share's
-launch geometry."""
+launch geometry; the row bound that workers vmapped together take."""
 import dataclasses
 import importlib.util
 import json
@@ -283,6 +283,8 @@ def test_share_geometry_of_the_cell():
     cfg = REF.model_config(FULL)
     g = moe.share_geometry(cfg, 4 * 2048)
     assert g["row_bound"] == 8192 * 6
+    # 2 x the even router's 6,144 rows, a multiple of the 128-row tile
+    assert g["tight_rows"] == 12288
     assert g["expected_rows"] == 6144
     assert g["bm"] == 128
     assert g["padded_rows"] == 8192 * 6 + 8 * 128
@@ -290,6 +292,41 @@ def test_share_geometry_of_the_cell():
     # every expert held, k <= E: the whole sorted buffer, as before
     full = get_config("dbrx-132b")
     assert moe.share_geometry(full, 100)["row_bound"] == 100 * 4
+    assert moe.share_geometry(full, 100)["tight_rows"] == 100 * 4
+
+
+@pytest.mark.parametrize("over", [(), (1,)], ids=["all_fit", "one_over"])
+def test_vmapped_workers_take_one_bound(over):
+    """The executor vmaps the local step over its workers: they take the
+    tight row bound together, or all fall back to the full bound when one
+    routes more rows than the tight bound holds; each worker's layer value
+    and gradients are its own either way."""
+    cfg = REF.model_config(SMALL)
+    T = B * S
+    assert moe.tight_rows(cfg, T) < moe.held_rows(cfg, T)
+    p = _layer(_weights()["layers"], 0)["moe"]
+    ps = [p, dict(p, router=p["router"] * 0.5)]
+    for w in over:   # every token to both held experts: 2T rows
+        ps[w] = dict(ps[w], select_bias=ps[w]["select_bias"].at[2:4].set(5.0))
+    x = _x()
+    for w, pw in enumerate(ps):
+        _, top_e, _ = moe.route(cfg, pw, (x + w).reshape(T, -1))
+        rows = int(jnp.sum((top_e >= 2) & (top_e < 4)))
+        assert (rows > moe.tight_rows(cfg, T)) == (w in over), rows
+    tang = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+
+    def f(p, x):
+        return jnp.sum(moe.apply_moe(cfg, p, x, train=True, impl="ref")[0]
+                       * tang)
+
+    vg = jax.value_and_grad(f, argnums=(0, 1))
+    stack = lambda *t: jax.tree_util.tree_map(lambda *a: jnp.stack(a), *t)
+    got, g_got = jax.jit(jax.vmap(vg))(stack(*ps), jnp.stack([x, x + 1]))
+    for w, pw in enumerate(ps):
+        want, g_want = jax.jit(vg)(pw, x + w)
+        np.testing.assert_allclose(float(got[w]), float(want), rtol=1e-5)
+        _assert_grads_close(jax.tree_util.tree_map(lambda a: a[w], g_got),
+                            g_want)
 
 
 def test_published_config_numbers():

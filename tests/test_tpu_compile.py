@@ -237,3 +237,44 @@ def test_grouped_matmul_backward_compiles_at_moonlight_widths(one_chip):
     names = re.findall(r"^\s*(?:ROOT )?%(\S+) = ", txt, re.M)
     assert sorted(n for n in names if re.match(reader.KERNEL, n)) == \
         sorted(calls)
+
+
+def test_held_share_dispatch_compiles_at_moonlight_widths(one_chip,
+                                                          monkeypatch):
+    """One MoE layer of Moonlight's expert share (8 of 64 experts held, 6
+    per token, published widths) on 1,024 tokens, differentiated under the
+    layer's remat and the executor's worker vmap, with the kernels the chip
+    runs: the tight and the full row bound sit in one conditional forward
+    and one backward; each bound's expert products run 3 times forward and
+    6 times backward (3 of them the backward's own forward), as the single
+    bound's did; the remat forward's copy is gone."""
+    import dataclasses
+    from collections import Counter
+
+    from repro.configs import get_config
+    from repro.kernels.moe_dispatch import launch_geometry
+    from repro.models import moe
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, n_held=8, held_offset=8))
+    T, d = 1024, cfg.d_model
+    R, R_t = moe.held_rows(cfg, T), moe.tight_rows(cfg, T)
+    assert (R, R_t) == (6144, 1536)
+    pad = lambda n: launch_geometry(n, d, 8, cfg.d_ff)["Np"]
+
+    layer = jax.checkpoint(
+        lambda p, x: moe.apply_moe(cfg, p, x, train=True)[0])
+    grad = jax.vmap(jax.value_and_grad(lambda p, x: jnp.sum(layer(p, x)),
+                                       argnums=(0, 1)))
+    p = jax.eval_shape(lambda k: moe.init_moe(k, cfg),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32))
+    p = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, (1,) + a.shape, a.dtype), p)
+    txt = _compiled_text(grad, p, _spec(one_chip, (1, 1, T, d)))
+    assert len(re.findall(r" conditional\(", txt)) == 2
+    rows = Counter(re.findall(
+        r"%grouped_matmul\.\d+ = \w+\[(?:1,)?(\d+),", txt))
+    assert rows == {str(pad(R_t)): 9, str(pad(R)): 9}, rows
+    assert len(re.findall(r"%grouped_matmul_wgrad\.\d+ = ", txt)) == 6

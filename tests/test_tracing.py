@@ -188,6 +188,11 @@ def test_shard_map_programs_carry_every_scope():
      "attention"),
     ("jit(body)/window/while/body/closed_call/local_step/vmap(jvp(model))/"
      "while/body/checkpoint/moe/jit(grouped_matmul)/pallas_call", "moe"),
+    ("jit(body)/window/while/body/closed_call/local_step/vmap(jvp(model))/"
+     "while/body/closed_call/moe/cond/branch_0_fun/vmap(moe_full_bound)/"
+     "jit(_take)", "moe_full_bound"),
+    ("checkpoint/moe/cond/branch_0_fun/vmap(moe_full_bound)/"
+     "transpose(jvp())/add", "moe_full_bound"),
 ])
 def test_scope_of(op_name, scope):
     assert tracing.scope_of(op_name) == scope
@@ -209,3 +214,26 @@ def test_moe_mla_program_carries_model_scopes():
           "labels": jnp.asarray([[[1.0, 0.0]]])}
     window = exe._wstep.lower(st, wb, jnp.float32(0.1)).compile().as_text()
     assert {"model", "attention", "moe"} <= _scopes(window)
+
+
+def test_held_share_program_carries_the_full_bound_scope():
+    """A held expert share whose tight row bound is tighter than the full
+    one: the window's fallback to the full bound (forward and backward)
+    sits under ``moe_full_bound``, inside ``moe``."""
+    import dataclasses
+
+    from repro.configs import get_smoke_config
+    from repro.models import moe
+
+    mcfg = get_smoke_config("moonlight-16b-a3b")
+    mcfg = dataclasses.replace(mcfg, moe=dataclasses.replace(
+        mcfg.moe, n_experts=8, n_held=2, held_offset=2))
+    assert moe.tight_rows(mcfg, 16) < moe.held_rows(mcfg, 16)
+    ccfg = coda.CoDAConfig(n_workers=1, p_pos=0.7)
+    key = jax.random.PRNGKey(2)
+    st = coda.init_state(key, mcfg, ccfg)
+    exe = coda.make_executor(mcfg, ccfg, "vmap", donate=False)
+    wb = {"tokens": jax.random.randint(key, (1, 1, 2, 8), 0, mcfg.vocab_size),
+          "labels": jnp.asarray([[[1.0, 0.0]]])}
+    window = exe._wstep.lower(st, wb, jnp.float32(0.1)).compile().as_text()
+    assert {"model", "moe", tracing.FULL_BOUND} <= _scopes(window)
